@@ -59,6 +59,8 @@ BENCH_JSONS = {
 
 
 def main() -> None:
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     from benchmarks import (batching_bench, faults_bench, fig3_rho_sweep,
                             observability_bench, paging_bench,
                             policies_bench, predictor_latency,

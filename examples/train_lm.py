@@ -23,11 +23,10 @@ def main():
     ap.add_argument("--ckpt", default="/tmp/repro_lm_ckpt")
     args = ap.parse_args()
 
-    argv = ["--arch", "smollm-360m", "--steps", str(args.steps),
+    arch = "smollm-360m" if args.full else "smollm-360m-reduced"
+    argv = ["--arch", arch, "--steps", str(args.steps),
             "--ckpt", args.ckpt, "--ckpt-every", "50",
             "--batch", "8", "--seq", "128", "--lr", "3e-3"]
-    if not args.full:
-        argv.append("--reduced")
     losses = train_mod.main(argv)
     drop = losses[0] - losses[-1]
     print(f"loss {losses[0]:.3f} -> {losses[-1]:.3f} (-{drop:.3f}) "

@@ -31,6 +31,11 @@ ALL_ARCH_NAMES = tuple(_ARCH_MODULES)
 
 
 def get_config(name: str) -> ArchConfig:
+    """The published config for ``name``; ``<name>-reduced`` resolves to
+    its tiny same-family test config (``ArchConfig.reduced``)."""
+    base = name.removesuffix("-reduced")
+    if base != name and base in _ARCH_MODULES:
+        return get_config(base).reduced()
     if name not in _ARCH_MODULES:
         raise KeyError(f"unknown arch {name!r}; known: {sorted(_ARCH_MODULES)}")
     mod = importlib.import_module(f"repro.configs.{_ARCH_MODULES[name]}")

@@ -1,7 +1,7 @@
 """smollm-360m [dense].
 
 32L d_model=960 15H (GQA kv=5) d_ff=2560 vocab=49152 — llama architecture,
-small.  [hf:HuggingFaceTB/SmolLM-135M; hf]
+small.  [hf:HuggingFaceTB/SmolLM-360M config.json]
 """
 
 from repro.configs.base import ATTN, ArchConfig

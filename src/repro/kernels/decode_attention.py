@@ -101,7 +101,7 @@ def _paged_decode_kernel(bt_ref, t_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_decode_attention_kernel(q, k_pages, v_pages, block_table, t, *,
-                                  interpret: bool = True):
+                                  interpret: bool = False):
     """Block-paged variant: K/V live in a shared physical page pool and
     each sequence reads its logical window through a block table.
 
@@ -161,7 +161,7 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table, t, *,
 
 
 def decode_attention_kernel(q, k, v, t, *, block_kv: int = 256,
-                            interpret: bool = True):
+                            interpret: bool = False):
     """q: (B, KV, G, hd) one query token, grouped; k, v: (B, KV, S, hd);
     t: scalar int32 absolute fill level (slots <= t attend; all slots once
     the ring has wrapped, t >= S).  Returns (B, KV, G, hd).

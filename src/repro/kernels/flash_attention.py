@@ -70,7 +70,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_kernel(q, k, v, *, causal: bool = True,
                            block_q: int = 128, block_kv: int = 128,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """q: (B, H, S, hd); k, v: (B, KV, S, hd) -> (B, H, S, hd)."""
     B, H, S, hd = q.shape
     KV = k.shape[1]

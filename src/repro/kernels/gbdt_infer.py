@@ -110,7 +110,7 @@ def _pad_grid(X, trees, n_classes, block_b, block_t):
     "n_classes", "block_b", "block_t", "interpret"))
 def gbdt_margins_kernel(X, feature, threshold, value, *, n_classes: int = 3,
                         block_b: int = 128, block_t: int = 48,
-                        interpret: bool = True):
+                        interpret: bool = False):
     """Dense layout. X: (B, F) f32; ensemble tensors (T, N) -> (B, K)."""
     B, F = X.shape
     T, N = feature.shape
@@ -150,7 +150,7 @@ def gbdt_margins_kernel(X, feature, threshold, value, *, n_classes: int = 3,
 def gbdt_margins_packed_kernel(X, feature, threshold, child, value, *,
                                depth: int, n_classes: int = 3,
                                block_b: int = 128, block_t: int = 48,
-                               interpret: bool = True):
+                               interpret: bool = False):
     """Packed layout (see core.ensemble_pack). Tensors (T, M) -> (B, K)."""
     B, F = X.shape
     T, M = feature.shape

@@ -21,6 +21,7 @@ from repro.core.gbdt import GBDTParams
 from repro.core.predictor import Predictor
 from repro.core.simulation import ServiceDist
 from repro.data.corpus import CLASS_NAMES, sample_dataset
+from repro.launch.compile_cache import setup_compile_cache
 from repro.serving.openai_api import CompletionRequest
 from repro.serving.server import ClairvoyantServer
 from repro.serving.service_time import ServiceTimeModel
@@ -66,6 +67,7 @@ def main(argv=None):
                          "drain's span timeline here (virtual time)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     model = ServiceTimeModel.from_arch(cfg, chips=args.chips)

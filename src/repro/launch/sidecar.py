@@ -15,8 +15,10 @@ Backends (one per replica):
   slept on the event loop and streamed as synthetic text
   (``--time-scale`` compresses wall time; the default for demos).
 * ``real`` — an actual fused on-device decode per request
-  (``RealEngine`` on the reduced smollm-360m stack, off the event loop
-  via a worker thread).
+  (``RealEngine`` for ``--arch`` at its published widths, random
+  weights from ``--seed``, a ``--max-len`` token window; off the event
+  loop via a worker thread).  Replicas of the real backend all share
+  one device.
 * ``http`` — proxy to external OpenAI-compatible upstreams
   (``--upstream host:port``, repeatable), with connect/read timeouts
   feeding the retry policy and per-replica circuit breakers.
@@ -44,6 +46,7 @@ import signal
 from repro.configs import get_config
 from repro.core.calibration import calibrate_tau
 from repro.core.simulation import ServiceDist
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.serve import build_predictor
 from repro.serving.faults import CircuitBreaker, FaultPlan, RetryPolicy
 from repro.serving.http_sidecar import Sidecar
@@ -81,14 +84,14 @@ def build_sidecar(args) -> Sidecar:
     elif args.backend == "real":
         from repro.serving.backends import InProcessBackend
         from repro.serving.engine import RealEngine
-        rcfg = get_config("smollm-360m").reduced()
         spec_kw = {}
         if getattr(args, "speculative", False):
-            dcfg = get_config(args.draft_model).reduced() \
-                if args.draft_model else rcfg
+            dcfg = get_config(args.draft_model) \
+                if args.draft_model else cfg
             spec_kw = dict(draft_cfg=dcfg, draft_k=args.draft_k,
                            draft_seed=args.seed)
-        backends = [InProcessBackend(RealEngine(rcfg, max_len=96,
+        backends = [InProcessBackend(RealEngine(cfg, seed=args.seed,
+                                                max_len=args.max_len,
                                                 **spec_kw))
                     for _ in range(args.replicas)]
         for i, b in enumerate(backends):
@@ -172,7 +175,9 @@ async def serve(args) -> None:
           f"wire_stats={sidecar.wire_stats}", flush=True)
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
+    """The sidecar's command line (``main`` parses it; ``chip_smoke.py``
+    builds its sidecar through it)."""
     from repro.core.policy import registered_names
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--host", default="127.0.0.1")
@@ -188,6 +193,9 @@ def main(argv=None):
     ap.add_argument("--model", default="clairvoyant-sim")
     ap.add_argument("--arch", default="gemma3-4b-edge")
     ap.add_argument("--chips", type=int, default=1)
+    ap.add_argument("--max-len", type=int, default=2048,
+                    help="real backend: KV window (prompt + generated "
+                         "tokens) per request")
     ap.add_argument("--dataset", default="sharegpt")
     ap.add_argument("--no-predictor", action="store_true")
     ap.add_argument("--tau-mult", type=float, default=3.0)
@@ -211,8 +219,8 @@ def main(argv=None):
                          "the tau calibration) apply the expected "
                          "speculative speedup to the service-time model")
     ap.add_argument("--draft-model", default=None,
-                    help="draft arch name (default: the reduced target "
-                         "arch — 100%% acceptance sanity mode)")
+                    help="draft arch name (default: the target arch "
+                         "itself — 100%% acceptance sanity mode)")
     ap.add_argument("--draft-k", type=int, default=4,
                     help="draft tokens proposed per verify step")
     ap.add_argument("--accept-rate", type=float, default=0.7,
@@ -234,7 +242,12 @@ def main(argv=None):
     ap.add_argument("--chaos-transient-rate", type=float, default=0.0,
                     help=">0: injected transient errors per second")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    setup_compile_cache()
     asyncio.run(serve(args))
 
 

@@ -1,6 +1,6 @@
 """End-to-end training driver.
 
-    PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --reduced \
+    PYTHONPATH=src python -m repro.launch.train --arch smollm-360m-reduced \
         --steps 50 --batch 8 --seq 128 --ckpt /tmp/ckpt
 
 Features exercised: sharded train step (pjit on the local mesh), synthetic
@@ -32,9 +32,8 @@ from repro.training.train_loop import (TrainState, abstract_train_state,
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="smollm-360m")
-    ap.add_argument("--reduced", action="store_true",
-                    help="use the reduced smoke config (CPU-friendly)")
+    ap.add_argument("--arch", default="smollm-360m",
+                    help="arch id; '<arch>-reduced' is its tiny CPU config")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -46,8 +45,6 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
     if cfg.audio_frontend or cfg.num_image_tokens:
         raise SystemExit("train.py drives text archs; use examples/ for "
                          "multimodal smoke runs")

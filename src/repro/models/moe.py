@@ -110,8 +110,6 @@ def apply_moe(cfg, p, x):
     all-to-all.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.sharding.compat import shard_map_fn
-    shard_map = shard_map_fn()
 
     B, S, D = x.shape
     E, K = cfg.num_experts, cfg.experts_per_token
@@ -138,10 +136,11 @@ def apply_moe(cfg, p, x):
     if use_manual:
         gs = _group_spec(mesh)
         gN = lambda n: P(*gs, *([None] * n))
-        xs, probs, info = shard_map(
+        xs, probs, info = jax.shard_map(
             route_and_dispatch, mesh=mesh,
             in_specs=(gN(2), P(None, None)),
             out_specs=(gN(3), gN(2), (gN(1), gN(1), gN(1), gN(1))),
+            check_vma=False,
         )(hg, p["router"])
     else:
         xs, probs, info = route_and_dispatch(hg, p["router"])
@@ -170,12 +169,12 @@ def apply_moe(cfg, p, x):
 
     if use_manual:
         gs = _group_spec(mesh)
-        combined = shard_map(
+        combined = jax.shard_map(
             combine, mesh=mesh,
             in_specs=(P(*gs, None, None, None),
                       (P(*gs, None), P(*gs, None), P(*gs, None),
                        P(*gs, None))),
-            out_specs=P(*gs, None, None))(out_e, info)
+            out_specs=P(*gs, None, None), check_vma=False)(out_e, info)
     else:
         combined = combine(out_e, info)
     out = combined.reshape(B, S, D).astype(x.dtype)

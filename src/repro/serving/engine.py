@@ -3,9 +3,10 @@
 Two execution modes share one interface:
 
 * ``RealEngine`` — jitted prefill + fused on-device greedy decode of an
-  actual LM (used by the examples, the serve benchmark and integration tests
-  with reduced configs on CPU; on TPU the same class serves full configs
-  with the Pallas decode kernels swapped in via kernels/ops.py);
+  actual LM in plain ``jax.numpy`` (models/attention.py; no Pallas kernel
+  is on this path).  The sidecar's ``--backend real`` serves full configs
+  with it; the examples, the serve benchmark and the integration tests
+  run reduced configs;
 * ``SimEngine`` — virtual-clock engine using a ServiceTimeModel (used by the
   queueing benchmarks, where thousands of requests are served);
 * ``BatchedRealEngine`` — bounded-concurrency micro-batching over
@@ -78,6 +79,12 @@ class SimEngine:
         return ttft, service
 
 
+def _top2(row: np.ndarray) -> tuple:
+    """(largest, second-largest) entry of a logit row."""
+    second, first = np.partition(row, -2)[-2:]
+    return float(first), float(second)
+
+
 # Padded (bucketed) prefill is only used when every block's per-position
 # state is causal-local; SSM/xLSTM recurrences fold pad tokens into their
 # state and MoE capacity routing lets pad tokens evict real ones.
@@ -85,7 +92,8 @@ _BUCKET_SAFE_KINDS = ("attn",)
 
 
 class RealEngine:
-    """Actual LM decode on device (reduced configs on this CPU container)."""
+    """Actual LM decode on device: bucketed prefill, then fused
+    segments of greedy decode."""
 
     def __init__(self, cfg, params=None, replica_id: int = 0, seed: int = 0,
                  max_len: int = 256, segment_len: int = 16,
@@ -322,12 +330,16 @@ class RealEngine:
 
         Kept in-tree as the equivalence oracle for the fused loop: same
         prefill, same stop-condition order, so token sequences must match
-        bitwise (tests/test_generate.py).
+        bitwise (tests/test_generate.py).  ``top2[i]`` holds the largest
+        and second-largest logit behind token ``i``: how near the greedy
+        choice came to a tie.
         """
         import jax.numpy as jnp
         t0 = time.monotonic()
         logits, caches, plen = self._run_prefill(prompt_ids)
-        tok = int(np.argmax(np.asarray(logits)[0]))
+        row = np.asarray(logits)[0]
+        tok = int(np.argmax(row))
+        top2 = [_top2(row)]
         ttft = time.monotonic() - t0
         out = [tok]
         for _ in range(max_new_tokens - 1):
@@ -337,11 +349,13 @@ class RealEngine:
                 break
             logits, caches = self._decode(
                 self.params, caches, {"tokens": jnp.full((1, 1), tok, jnp.int32)})
-            tok = int(np.argmax(np.asarray(logits)[0]))
+            row = np.asarray(logits)[0]
+            tok = int(np.argmax(row))
+            top2.append(_top2(row))
             out.append(tok)
         self.served += 1
         return {"tokens": out, "ttft_s": ttft,
-                "service_s": time.monotonic() - t0}
+                "service_s": time.monotonic() - t0, "top2": top2}
 
 
 class BatchedRealEngine(RealEngine):

@@ -28,18 +28,11 @@ loop's; ``benchmarks/serve_bench.py`` measures the ratio and writes it to
 from __future__ import annotations
 
 import functools
-import warnings
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-# Older CPU jaxlibs ignore donation with a warning; the fused loop is still
-# correct (the copy just reappears).  Suppressed around the segment call
-# only — not globally — so applications keep the signal for their own jits.
-_DONATION_WARNING = "Some donated buffers were not usable"
-
 
 class FusedDecoder:
     """Device-resident segmented greedy decoder for one ``LM``.
@@ -127,10 +120,8 @@ class FusedDecoder:
             if cancel_check is not None and cancel_check():
                 cancelled = True
                 break
-            with warnings.catch_warnings():
-                warnings.filterwarnings("ignore", message=_DONATION_WARNING)
-                buf, tok, produced, caches, stopped = self._segment(
-                    params, caches, tok, produced, plen, max_new, eos)
+            buf, tok, produced, caches, stopped = self._segment(
+                params, caches, tok, produced, plen, max_new, eos)
             segments += 1
             n_new = int(produced) - len(out)     # one host sync per segment
             buf_np = np.asarray(buf)
@@ -290,12 +281,10 @@ class SpeculativeDecoder:
             if cancel_check is not None and cancel_check():
                 cancelled = True
                 break
-            with warnings.catch_warnings():
-                warnings.filterwarnings("ignore", message=_DONATION_WARNING)
-                (emit, n_emit, tok, produced, has_tail, tail, caches,
-                 dcaches, stopped) = self._round(
-                    params, draft_params, caches, dcaches, tok, produced,
-                    has_tail, tail, plen, max_new, eos)
+            (emit, n_emit, tok, produced, has_tail, tail, caches,
+             dcaches, stopped) = self._round(
+                params, draft_params, caches, dcaches, tok, produced,
+                has_tail, tail, plen, max_new, eos)
             rounds += 1
             n = int(n_emit)                  # one host sync per round
             new = [int(x) for x in np.asarray(emit)[:n]]
@@ -469,10 +458,8 @@ class LaneDecoder:
         occupied-but-stopped lanes.
         """
         C = self.n_lanes
-        with warnings.catch_warnings():
-            warnings.filterwarnings("ignore", message=_DONATION_WARNING)
-            buf, tok_j, produced_j, caches, stopped, dead = self._segment(
-                params, caches, tok, produced, plen, max_new, eos, active)
+        buf, tok_j, produced_j, caches, stopped, dead = self._segment(
+            params, caches, tok, produced, plen, max_new, eos, active)
         buf_np = np.asarray(buf)                  # one host sync per segment
         produced_np = np.array(produced_j)
         new_tokens = [
@@ -812,12 +799,10 @@ class _SpecLaneMixin:
         stashes per-lane ``last_drafted`` / ``last_accepted`` host arrays
         for the engine's acceptance accounting."""
         C = self.n_lanes
-        with warnings.catch_warnings():
-            warnings.filterwarnings("ignore", message=_DONATION_WARNING)
-            (buf, tok_j, produced_j, caches, stopped, dead, drafted,
-             accepted) = self._spec_segment(
-                params, self.draft_params, caches, tok, produced, plen,
-                max_new, eos, active)
+        (buf, tok_j, produced_j, caches, stopped, dead, drafted,
+         accepted) = self._spec_segment(
+            params, self.draft_params, caches, tok, produced, plen,
+            max_new, eos, active)
         buf_np = np.asarray(buf)                  # one host sync per segment
         produced_np = np.array(produced_j)
         self.last_drafted = np.array(drafted)

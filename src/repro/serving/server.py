@@ -84,6 +84,7 @@ from repro.serving.observability import Observability, record_service_spans
 from repro.serving.openai_api import CompletionRequest, CompletionResponse
 from repro.serving.service_time import ServiceTimeModel, sample_output_tokens
 from repro.data.tokenizer import HashTokenizer, approx_token_len
+from repro.serving.backends import tokens_to_text
 
 
 class ClairvoyantServer:
@@ -849,7 +850,7 @@ class ClairvoyantServer:
             if emit_spans is not None:
                 emit_spans()
             self._finish(CompletionResponse(
-                request_id=req.req_id, text="",
+                request_id=req.req_id, text=tokens_to_text(tokens),
                 tokens_generated=len(tokens),
                 queue_wait_s=req.start - req.arrival,
                 service_s=total_service,
@@ -969,7 +970,7 @@ class ClairvoyantServer:
                                     service_estimate=out["service_s"])
             self.router.record_success(rep.replica_id, req.finish)
             self._finish(CompletionResponse(
-                request_id=req.req_id, text="",
+                request_id=req.req_id, text=tokens_to_text(tokens),
                 tokens_generated=len(tokens),
                 queue_wait_s=req.start - req.arrival,
                 service_s=req.finish - req.start,

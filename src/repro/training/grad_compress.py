@@ -68,13 +68,13 @@ def make_compressed_allreduce(mesh, axis_name: str = "data"):
     """Returns mean_fn(tree) -> tree, reducing over ``axis_name`` with int8
     compression + error feedback state threaded explicitly."""
     from jax.sharding import PartitionSpec as P
-    from repro.sharding.compat import shard_map_unchecked
 
     def one(g):
         fn = functools.partial(compressed_psum_mean, axis_name=axis_name)
         # output IS replicated (phase-2 all-gather), but the checker cannot
         # infer that through the quantize/dequantize ops
-        return shard_map_unchecked(fn, mesh, P(), P())(g)
+        return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False)(g)
 
     def mean_fn(tree):
         return jax.tree.map(one, tree)

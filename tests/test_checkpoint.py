@@ -81,7 +81,7 @@ def test_async_checkpointer(tmp_path):
 def test_train_resume_equivalence(tmp_path):
     """Kill/restart: N steps straight == N/2 steps + restart + N/2 steps."""
     from repro.launch import train as train_mod
-    args = ["--arch", "smollm-360m", "--reduced", "--batch", "4",
+    args = ["--arch", "smollm-360m-reduced", "--batch", "4",
             "--seq", "32", "--lr", "1e-3"]
     losses_straight = train_mod.main(args + ["--steps", "6"])
     ck = str(tmp_path / "ck")

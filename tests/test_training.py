@@ -67,7 +67,7 @@ def test_microbatch_accumulation_matches_full_batch():
 
 def test_loss_decreases_over_short_run():
     from repro.launch import train as train_mod
-    losses = train_mod.main(["--arch", "smollm-360m", "--reduced",
+    losses = train_mod.main(["--arch", "smollm-360m-reduced",
                              "--steps", "30", "--batch", "8", "--seq", "64",
                              "--lr", "1e-2"])
     assert losses[-1] < losses[0] - 0.4, (losses[0], losses[-1])
