@@ -7,7 +7,8 @@ backends (Ollama / llama.cpp-shaped processes).  The batch drains in
 replica, behind a uniform async contract:
 
     out = await backend.generate(prompt, max_new_tokens=n,
-                                 on_segment=push, cancel_cb=poll)
+                                 on_segment=push, cancel_cb=poll,
+                                 req_id=rid)
     # {"text", "tokens", "ttft_s", "service_s", "cancelled"}
 
 * ``on_segment(delta: str)`` streams text out at fused-decode segment
@@ -17,6 +18,13 @@ replica, behind a uniform async contract:
   prior :meth:`Backend.request_cancel`) drains the request with
   ``cancelled=True`` — §3.4 semantics, now wire-triggerable by a client
   disconnect or a deadline expiry.
+* with a flight recorder attached (``recorder``, set by
+  ``ClairvoyantServer.attach_observability``), every adapter records the
+  request ``req_id``'s measured ``prefill`` / ``decode`` /
+  ``decode_segment`` spans on the replica's track, on the recorder's
+  clock: the engine times its own (``RealEngine.generate``), the
+  simulated backend times its sleeps, the HTTP adapter stamps SSE chunk
+  arrivals.
 * injected faults surface as raises: :class:`EngineCrash` from the
   shared ``FaultInjector``'s segment polls, and
   :class:`TransientBackendError` from the HTTP adapter's connect/read
@@ -49,6 +57,7 @@ from typing import Callable, Optional
 
 from repro.data.tokenizer import approx_token_len
 from repro.serving.faults import TransientBackendError
+from repro.serving.observability import NO_REGION
 from repro.serving.service_time import ServiceTimeModel
 
 
@@ -74,6 +83,8 @@ class Backend:
         self.busy_until = 0.0
         self.served = 0
         self.fault_injector = None
+        #: optional FlightRecorder (see the module docstring)
+        self.recorder = None
         #: virtual clock supplied by the sidecar (falls back to wall time
         #: from construction) — fault windows trigger against this
         self.clock: Optional[Callable[[], float]] = None
@@ -98,7 +109,8 @@ class Backend:
         return self._cancel or (cancel_cb is not None and cancel_cb())
 
     async def generate(self, prompt: str, *, max_new_tokens: int = 32,
-                       on_segment=None, cancel_cb=None) -> dict:
+                       on_segment=None, cancel_cb=None,
+                       req_id: Optional[int] = None) -> dict:
         raise NotImplementedError
 
     async def probe(self) -> bool:
@@ -133,8 +145,11 @@ class SimTextBackend(Backend):
         self.segment_tokens = int(segment_tokens)
 
     async def generate(self, prompt: str, *, max_new_tokens: int = 32,
-                       on_segment=None, cancel_cb=None) -> dict:
+                       on_segment=None, cancel_cb=None,
+                       req_id: Optional[int] = None) -> dict:
         self._cancel = False
+        rec = self.recorder
+        trk = None if rec is None else f"replica{self.replica_id}"
         t0 = time.monotonic()
         ptoks = approx_token_len(prompt)
         n = max(1, int(max_new_tokens))
@@ -142,25 +157,32 @@ class SimTextBackend(Backend):
         prefill = (self.model.overhead_s
                    + ptoks / self.model.prefill_tok_per_s) * self.time_scale
         per_tok = max(0.0, full - prefill) / n
-        await asyncio.sleep(prefill)
+        with NO_REGION if rec is None else rec.region(
+                "prefill", req_id, trk, tokens=ptoks):
+            await asyncio.sleep(prefill)
         ttft = time.monotonic() - t0
         tokens = [0]
-        if on_segment is not None:
-            on_segment(tokens_to_text(tokens))     # prefill token
         cancelled = False
-        while len(tokens) < n:
-            if self._poll_cancel(cancel_cb):       # may raise EngineCrash
-                cancelled = True
-                break
-            k = min(self.segment_tokens, n - len(tokens))
-            f = 1.0 if self.fault_injector is None \
-                else self.fault_injector.stall_factor(self.replica_id,
-                                                      self.now())
-            await asyncio.sleep(per_tok * k * f)
-            new = list(range(len(tokens), len(tokens) + k))
-            tokens.extend(new)
+        with NO_REGION if rec is None else rec.region("decode", req_id, trk):
             if on_segment is not None:
-                on_segment(" " + tokens_to_text(new))
+                on_segment(tokens_to_text(tokens))     # prefill token
+            seg = 0
+            while len(tokens) < n:
+                if self._poll_cancel(cancel_cb):       # may raise EngineCrash
+                    cancelled = True
+                    break
+                with NO_REGION if rec is None else rec.region(
+                        "decode_segment", req_id, trk, seg=seg):
+                    k = min(self.segment_tokens, n - len(tokens))
+                    f = 1.0 if self.fault_injector is None \
+                        else self.fault_injector.stall_factor(
+                            self.replica_id, self.now())
+                    await asyncio.sleep(per_tok * k * f)
+                    new = list(range(len(tokens), len(tokens) + k))
+                    tokens.extend(new)
+                    if on_segment is not None:
+                        on_segment(" " + tokens_to_text(new))
+                seg += 1
         self.served += not cancelled
         self._cancel = False
         return {"text": tokens_to_text(tokens), "tokens": len(tokens),
@@ -191,11 +213,21 @@ class InProcessBackend(Backend):
         if "engine" in self.__dict__:
             self.engine.fault_injector = inj
 
+    @property
+    def recorder(self):
+        return self.engine.recorder
+
+    @recorder.setter
+    def recorder(self, rec):
+        if "engine" in self.__dict__:
+            self.engine.recorder = rec
+
     def request_cancel(self) -> None:
         self.engine.request_cancel()
 
     async def generate(self, prompt: str, *, max_new_tokens: int = 32,
-                       on_segment=None, cancel_cb=None) -> dict:
+                       on_segment=None, cancel_cb=None,
+                       req_id: Optional[int] = None) -> dict:
         loop = asyncio.get_running_loop()
         ids = self.tokenizer.encode(prompt)
         first = [True]
@@ -213,7 +245,7 @@ class InProcessBackend(Backend):
 
         out = await asyncio.to_thread(
             self.engine.generate, ids, max_new_tokens=max_new_tokens,
-            cancel_cb=cancel_cb, on_segment=seg)
+            cancel_cb=cancel_cb, on_segment=seg, req_id=req_id)
         self.served = self.engine.served
         res = {"text": tokens_to_text(out["tokens"]),
                "tokens": len(out["tokens"]), "ttft_s": out["ttft_s"],
@@ -325,6 +357,7 @@ class HTTPBackend(Backend):
     # ------------------------------------------------------------ generate
     async def generate(self, prompt: str, *, max_new_tokens: int = 32,
                        on_segment=None, cancel_cb=None,
+                       req_id: Optional[int] = None,
                        extra: Optional[dict] = None,
                        headers: Optional[dict] = None) -> dict:
         self._cancel = False
@@ -338,14 +371,20 @@ class HTTPBackend(Backend):
         if extra:
             payload.update(extra)
         body = json.dumps(payload).encode()
+        rec = self.recorder
+        c0 = None if rec is None else rec.clock()
         t0 = time.monotonic()
         reader, writer, status, hdrs = await self._request(
             "POST", self.path, body, headers)
         try:
             ctype = hdrs.get("content-type", "")
             if stream and status == 200 and "text/event-stream" in ctype:
-                return await self._consume_sse(reader, on_segment,
-                                               cancel_cb, t0)
+                arrivals = None if rec is None else []
+                out = await self._consume_sse(reader, on_segment,
+                                              cancel_cb, t0, arrivals)
+                if arrivals:
+                    self._record_stream(rec, req_id, c0, arrivals)
+                return out
             raw = await self._read(reader.read(-1))
             if status != 200:
                 # upstream refusal/failure: retryable from this side
@@ -365,11 +404,25 @@ class HTTPBackend(Backend):
         finally:
             self._close(writer)
 
+    def _record_stream(self, rec, req_id, c0: float, arrivals) -> None:
+        """The stream's spans by chunk arrival: ``prefill`` up to the
+        first delta, then a ``decode_segment`` between consecutive
+        deltas, inside ``decode``."""
+        trk = f"replica{self.replica_id}"
+        c1 = rec.clock()
+        spans = [("prefill", req_id, c0, arrivals[0], trk, None),
+                 ("decode", req_id, arrivals[0], c1, trk, None)]
+        spans += [("decode_segment", req_id, a, b, trk, {"seg": k})
+                  for k, (a, b) in enumerate(zip(arrivals, arrivals[1:]))]
+        rec.extend(spans)
+
     async def _consume_sse(self, reader, on_segment, cancel_cb,
-                           t0: float) -> dict:
+                           t0: float, arrivals=None) -> dict:
         """Drain an SSE stream: forward deltas, honor cancellation
         between frames (close the upstream connection — our disconnect
-        IS the cancel signal to a sidecar upstream)."""
+        IS the cancel signal to a sidecar upstream).  ``arrivals`` (a
+        list, when tracing) collects each delta's arrival on the
+        recorder's clock."""
         text_parts = []
         ttft = None
         finish = None
@@ -397,6 +450,8 @@ class HTTPBackend(Backend):
             choice = doc.get("choices", [{}])[0]
             delta = choice.get("delta", {}).get("content")
             if delta:
+                if arrivals is not None:
+                    arrivals.append(self.recorder.clock())
                 if ttft is None:
                     ttft = time.monotonic() - t0
                 text_parts.append(delta)

@@ -48,11 +48,13 @@ response to free the dispatch slot within ``segment_len`` tokens.
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Optional
 
 import numpy as np
 
+from repro.serving.observability import NO_REGION
 from repro.serving.service_time import ServiceTimeModel
 
 
@@ -120,9 +122,10 @@ class RealEngine:
         # segment boundaries (same join points as cancellation), where an
         # injected crash surfaces as an EngineCrash raise out of generate
         self.fault_injector = None
-        # optional serving.observability.FlightRecorder: the batched lane
-        # loop stamps per-lane prefill/decode/decode_segment spans on it
-        # (timestamps from the caller's now_fn, so virtual clocks work)
+        # optional serving.observability.FlightRecorder: ``generate``
+        # times its prefill/decode regions on it (on the recorder's
+        # clock); the batched lane loop stamps per-lane
+        # prefill/decode/decode_segment spans (from the caller's now_fn)
         self.recorder = None
         self._pending_items: list = []
 
@@ -263,7 +266,8 @@ class RealEngine:
     # ------------------------------------------------------------- generate
     def generate(self, prompt_ids: np.ndarray, max_new_tokens: int = 32,
                  eos_id: Optional[int] = None, cancel_cb=None,
-                 segment_len: Optional[int] = None, on_segment=None) -> dict:
+                 segment_len: Optional[int] = None, on_segment=None,
+                 req_id: Optional[int] = None) -> dict:
         """Fused greedy decode.  prompt_ids: (S,) ints.
 
         Returns {"tokens", "ttft_s", "service_s", "cancelled", "segments"}.
@@ -271,11 +275,21 @@ class RealEngine:
         cancel flag between scan segments.  ``on_segment(new_tokens)``
         streams tokens out at each segment boundary (the sidecar's SSE
         flush points — see :meth:`FusedDecoder.decode`).
+
+        With a recorder attached, the request ``req_id``'s ``prefill``
+        (the dispatch through the host argmax of the first token),
+        ``decode`` and the decode loop's regions are timed here, on the
+        replica's track.
         """
         self._cancel = False
+        rec = self.recorder
+        region = None if rec is None else functools.partial(
+            rec.region, req_id=req_id, track=f"replica{self.replica_id}")
         t0 = time.monotonic()
-        logits, caches, plen = self._run_prefill(prompt_ids)
-        tok = int(np.argmax(np.asarray(logits)[0]))
+        with NO_REGION if region is None else region(
+                "prefill", tokens=len(prompt_ids)):
+            logits, caches, plen = self._run_prefill(prompt_ids)
+            tok = int(np.argmax(np.asarray(logits)[0]))
         ttft = time.monotonic() - t0
 
         def cancelled():
@@ -285,19 +299,22 @@ class RealEngine:
                 self.fault_injector.poll_segment(self.replica_id)
             return self._cancel or (cancel_cb is not None and cancel_cb())
 
-        if self.speculative:
-            _, dcaches, _ = self._run_prefill(
-                prompt_ids, prefill=self._draft_prefill,
-                params=self.draft_params)
-            out = self._spec_decoder.decode(
-                self.params, self.draft_params, caches, dcaches, tok, plen,
-                max_new_tokens, eos_id=eos_id, cancel_check=cancelled,
-                on_segment=on_segment)
-        else:
-            dec = self._decoder(segment_len or self.segment_len)
-            out = dec.decode(self.params, caches, tok, plen, max_new_tokens,
-                             eos_id=eos_id, cancel_check=cancelled,
-                             on_segment=on_segment)
+        with NO_REGION if region is None else region("decode"):
+            if self.speculative:
+                _, dcaches, _ = self._run_prefill(
+                    prompt_ids, prefill=self._draft_prefill,
+                    params=self.draft_params)
+                out = self._spec_decoder.decode(
+                    self.params, self.draft_params, caches, dcaches, tok,
+                    plen, max_new_tokens, eos_id=eos_id,
+                    cancel_check=cancelled, on_segment=on_segment,
+                    region=region)
+            else:
+                dec = self._decoder(segment_len or self.segment_len)
+                out = dec.decode(self.params, caches, tok, plen,
+                                 max_new_tokens, eos_id=eos_id,
+                                 cancel_check=cancelled,
+                                 on_segment=on_segment, region=region)
         self.served += 1
         self._cancel = False
         res = {"tokens": out["tokens"], "ttft_s": ttft,

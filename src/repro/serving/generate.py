@@ -34,6 +34,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serving.observability import NO_REGION
+
+
 class FusedDecoder:
     """Device-resident segmented greedy decoder for one ``LM``.
 
@@ -88,7 +91,7 @@ class FusedDecoder:
 
     def decode(self, params, caches, first_token: int, prompt_len: int,
                max_new_tokens: int, eos_id: Optional[int] = None,
-               cancel_check=None, on_segment=None) -> dict:
+               cancel_check=None, on_segment=None, region=None) -> dict:
         """Greedy-decode from a prefilled cache.
 
         ``first_token`` is the prefill argmax (already emitted).  Returns
@@ -103,33 +106,59 @@ class FusedDecoder:
         for the sidecar (and the same join points where cancellation and
         injected crashes land).  Exceptions from the callback propagate —
         emission is part of serving the request.
+
+        ``region(name, **args)`` (a recorder's ``region`` bound to the
+        request and track; None when untraced) times the host work of
+        the loop: ``decode_poll`` (``cancel_check``), and per segment a
+        ``decode_segment`` holding ``decode_dispatch`` (the jitted call,
+        until it returns), ``decode_sync`` (the reads of ``produced`` and
+        the token buffer), ``decode_emit`` (``on_segment``) and
+        ``decode_stop`` (the read of ``stopped``).
         """
         out = [int(first_token)]
         if on_segment is not None:
-            on_segment([int(first_token)])
+            with NO_REGION if region is None else region("decode_emit"):
+                on_segment([int(first_token)])
         tok = jnp.asarray(first_token, jnp.int32)
         produced = jnp.asarray(1, jnp.int32)
         plen = jnp.asarray(prompt_len, jnp.int32)
         max_new = jnp.asarray(max_new_tokens, jnp.int32)
         eos = jnp.asarray(-1 if eos_id is None else eos_id, jnp.int32)
+        K = self.segment_len
+        steps = min(max_new_tokens, self.max_len - prompt_len) - 1
         cancelled = False
         segments = 0
         # The first segment's predicate replays the oracle's post-prefill
         # checks, so a request that is already complete runs zero steps.
         while True:
-            if cancel_check is not None and cancel_check():
-                cancelled = True
-                break
-            buf, tok, produced, caches, stopped = self._segment(
-                params, caches, tok, produced, plen, max_new, eos)
-            segments += 1
-            n_new = int(produced) - len(out)     # one host sync per segment
-            buf_np = np.asarray(buf)
-            new = [int(x) for x in buf_np[:n_new]]
-            out.extend(new)
-            if on_segment is not None and new:
-                on_segment(new)
-            if bool(stopped):
+            if cancel_check is not None:
+                with NO_REGION if region is None else region("decode_poll"):
+                    cancelled = bool(cancel_check())
+                if cancelled:
+                    break
+            with NO_REGION if region is None else region(
+                    "decode_segment", seg=segments, plen=prompt_len,
+                    first_step=segments * K,
+                    steps=max(0, min(K, steps - segments * K))):
+                with NO_REGION if region is None \
+                        else region("decode_dispatch"):
+                    buf, tok, produced, caches, stopped = self._segment(
+                        params, caches, tok, produced, plen, max_new, eos)
+                segments += 1
+                with NO_REGION if region is None else region("decode_sync"):
+                    n_new = int(produced) - len(out)  # one sync per segment
+                    buf_np = np.asarray(buf)
+                new = [int(x) for x in buf_np[:n_new]]
+                out.extend(new)
+                if on_segment is not None and new:
+                    with NO_REGION if region is None \
+                            else region("decode_emit"):
+                        on_segment(new)
+                # the stop flag is read after the emit: the event loop can
+                # then write the delta while this thread waits on the read
+                with NO_REGION if region is None else region("decode_stop"):
+                    stop = bool(stopped)
+            if stop:
                 break
         return {"tokens": out, "cancelled": cancelled, "segments": segments,
                 "caches": caches}
@@ -250,11 +279,12 @@ class SpeculativeDecoder:
     def decode(self, params, draft_params, caches, dcaches,
                first_token: int, prompt_len: int, max_new_tokens: int,
                eos_id: Optional[int] = None, cancel_check=None,
-               on_segment=None) -> dict:
+               on_segment=None, region=None) -> dict:
         """Greedy-decode from prefilled target + draft caches.
 
         Mirrors :meth:`FusedDecoder.decode` (same result keys, same
-        cancel/stream join points — here every round is a segment), plus
+        cancel/stream join points — here every round is a segment and
+        ``region`` times each as a ``decode_segment``), plus
         ``drafted``/``accepted`` counters (``accepted / drafted`` is the
         observed acceptance rate the admission layer feeds back into its
         effective-service-time key).
@@ -281,21 +311,24 @@ class SpeculativeDecoder:
             if cancel_check is not None and cancel_check():
                 cancelled = True
                 break
-            (emit, n_emit, tok, produced, has_tail, tail, caches,
-             dcaches, stopped) = self._round(
-                params, draft_params, caches, dcaches, tok, produced,
-                has_tail, tail, plen, max_new, eos)
-            rounds += 1
-            n = int(n_emit)                  # one host sync per round
-            new = [int(x) for x in np.asarray(emit)[:n]]
-            out.extend(new)
-            drafted += K
-            accepted += n - 1
-            if on_segment is not None and new:
-                on_segment(new)
+            with NO_REGION if region is None else region(
+                    "decode_segment", seg=rounds):
+                (emit, n_emit, tok, produced, has_tail, tail, caches,
+                 dcaches, stopped) = self._round(
+                    params, draft_params, caches, dcaches, tok, produced,
+                    has_tail, tail, plen, max_new, eos)
+                rounds += 1
+                n = int(n_emit)                  # one host sync per round
+                new = [int(x) for x in np.asarray(emit)[:n]]
+                out.extend(new)
+                drafted += K
+                accepted += n - 1
+                if on_segment is not None and new:
+                    on_segment(new)
+                stop = bool(stopped)
             tok_h = new[-1]
             produced_h += n
-            if bool(stopped):
+            if stop:
                 break
         return {"tokens": out, "cancelled": cancelled, "segments": rounds,
                 "caches": caches, "draft_caches": dcaches,

@@ -68,7 +68,7 @@ import time
 from typing import Dict, List, Optional
 
 from repro.serving.faults import EngineCrash, TransientBackendError
-from repro.serving.observability import Observability
+from repro.serving.observability import NO_REGION, Observability
 from repro.serving.openai_api import (HTTP_STATUS, CompletionRequest,
                                       CompletionResponse,
                                       chat_completion_body, chat_chunk_body,
@@ -195,6 +195,9 @@ class Sidecar:
         if getattr(server, "obs", None) is None:
             server.attach_observability(Observability.default(tracing=False))
         self.obs = server.obs
+        if self.obs.recorder is not None:
+            # every span and region of the live path on the sidecar's clock
+            self.obs.recorder.clock = self.now
         if self.obs.metrics is not None:
             self._register_wire_metrics()
 
@@ -318,56 +321,55 @@ class Sidecar:
 
     async def _serve_one(self, rep, backend, req) -> None:
         srv = self.server
-        t = max(self.now(), req.arrival)
-        if srv._maybe_shed(rep, req, t):
-            return                           # pre-dispatch expiry: shed
-        # injected transient at dispatch (same point as the drains)
-        if srv.faults is not None:
-            spec = srv.faults.transient_due(rep.replica_id, t)
-            if spec is not None:
-                nb = srv._retry_or_fail(rep, req, t,
-                                        TransientBackendError(
-                                            "injected transient backend "
-                                            "error"))
-                await asyncio.sleep(max(0.0, nb - t))    # serial backoff
-                return
-        if req.start is None:
-            req.start = t
         rid = req.req_id
-        creq = srv._inflight.get(rid)
-        n_new = max(1, min(creq.max_tokens if creq else self.max_new_tokens,
-                           req.meta.get("output_tokens",
-                                        self.max_new_tokens)))
-        dl = srv._deadline_of(req)
-        deadline_hit = []
-
-        def cancel_cb() -> bool:
-            if self._hard_stop:
-                return True
-            if dl is not None and (self.now() - req.arrival) > dl:
-                deadline_hit.append(True)
-                return True
-            return False
-
-        w = self._waiters.get(rid)
-        on_segment = w.push_delta if w is not None and creq is not None \
-            and creq.stream else None
         rec = self.obs.recorder
-        seg_marks: List[float] = []
-        if rec is not None:
-            # wrap the delta pusher so every segment boundary leaves a
-            # timestamp mark (streamed or not) for decode_segment spans
-            _push = on_segment
+        trk = None if rec is None else f"replica{rep.replica_id}"
+        backoff = None
+        with NO_REGION if rec is None else rec.region(
+                "dispatch", rid, trk, waiting=len(rep.queue),
+                promoted=bool(req.promoted)):
+            t = max(self.now(), req.arrival)
+            if srv._maybe_shed(rep, req, t):
+                return                       # pre-dispatch expiry: shed
+            # injected transient at dispatch (same point as the drains)
+            spec = None if srv.faults is None \
+                else srv.faults.transient_due(rep.replica_id, t)
+            if spec is not None:
+                backoff = srv._retry_or_fail(
+                    rep, req, t, TransientBackendError(
+                        "injected transient backend error")) - t
+            else:
+                if req.start is None:
+                    req.start = t
+                    if rec is not None:
+                        rec.span("queue_wait", rid, req.arrival, t,
+                                 track=f"req{rid}")
+                creq = srv._inflight.get(rid)
+                n_new = max(1, min(
+                    creq.max_tokens if creq else self.max_new_tokens,
+                    req.meta.get("output_tokens", self.max_new_tokens)))
+                dl = srv._deadline_of(req)
+                deadline_hit = []
 
-            def on_segment(delta, _p=_push, _m=seg_marks):
-                _m.append(self.now())
-                if _p is not None:
-                    _p(delta)
-        srv._decoding[rep.replica_id] = rid
+                def cancel_cb() -> bool:
+                    if self._hard_stop:
+                        return True
+                    if dl is not None and (self.now() - req.arrival) > dl:
+                        deadline_hit.append(True)
+                        return True
+                    return False
+
+                w = self._waiters.get(rid)
+                on_segment = w.push_delta if w is not None \
+                    and creq is not None and creq.stream else None
+                srv._decoding[rep.replica_id] = rid
+        if backoff is not None:
+            await asyncio.sleep(max(0.0, backoff))       # serial backoff
+            return
         try:
             out = await backend.generate(req.prompt, max_new_tokens=n_new,
                                          on_segment=on_segment,
-                                         cancel_cb=cancel_cb)
+                                         cancel_cb=cancel_cb, req_id=rid)
         except Exception as e:
             t_err = self.now()
             if isinstance(e, EngineCrash) and e.repair_s > 0:
@@ -380,6 +382,15 @@ class Sidecar:
             return
         finally:
             srv._decoding.pop(rep.replica_id, None)
+        with NO_REGION if rec is None else rec.region("finish", rid, trk):
+            self._finish_served(rep, backend, req, out, deadline_hit)
+
+    def _finish_served(self, rep, backend, req, out: dict,
+                       deadline_hit: list) -> None:
+        """The terminal of a request the backend served (ok, or cancelled
+        or timed out at a segment boundary)."""
+        srv = self.server
+        rid = req.req_id
         t_end = self.now()
         backend.busy_until = t_end
         retries = req.meta.get("fault_retries", 0)
@@ -393,25 +404,6 @@ class Sidecar:
                       degraded=bool(req.meta.get("degraded")),
                       accept_rate=out.get("accept_rate"))
         req.finish = t_end
-        if rec is not None:
-            # spans land before _finish so the root "request" span (the
-            # observe_terminal hook) stretches over them
-            trk = f"replica{rep.replica_id}"
-            t_gen0 = max(t, t_end - out["service_s"])
-            t_pref = min(t_gen0 + max(out["ttft_s"], 0.0), t_end)
-            rec.span("queue_wait", rid, req.arrival, t_gen0,
-                     track=f"req{rid}")
-            rec.span("prefill", rid, t_gen0, t_pref, track=trk)
-            rec.span("decode", rid, t_pref, t_end, track=trk)
-            edges = [t_pref]
-            for m in seg_marks:           # measured segment boundaries
-                if t_pref < m < t_end:
-                    edges.append(max(m, edges[-1]))
-            edges.append(t_end)
-            for i in range(len(edges) - 1):
-                if edges[i + 1] > edges[i]:
-                    rec.span("decode_segment", rid, edges[i],
-                             edges[i + 1], track=trk)
         if out["cancelled"]:
             if rid in srv._disconnected:
                 srv._disconnected.discard(rid)
@@ -619,22 +611,29 @@ class Sidecar:
         """SSE writer: chunk frames at segment boundaries, a final frame
         carrying ``finish_reason`` (the terminal status), an error frame
         for non-ok terminals, then ``[DONE]``.  A pre-first-delta
-        failure degrades to a plain JSON error response."""
+        failure degrades to a plain JSON error response.  With a recorder,
+        each delta's write and drain is an ``sse_write`` region (``seg``
+        counts the deltas from 0, the prefill token's)."""
         started = False
+        seg = 0
         while True:
             kind, payload = await w.queue.get()
             if kind == "delta":
-                if not started:
-                    head = ("HTTP/1.1 200 OK\r\n"
-                            "Content-Type: text/event-stream\r\n"
-                            "Cache-Control: no-cache\r\n"
-                            "Connection: close\r\n\r\n")
-                    writer.write(head.encode("ascii"))
-                    started = True
-                frame = "data: " + json.dumps(chat_chunk_body(
-                    rid, self.model, payload)) + "\n\n"
-                writer.write(frame.encode())
-                await self._guarded_drain(writer, rid)
+                rec = self.obs.recorder
+                with NO_REGION if rec is None else rec.region(
+                        "sse_write", rid, f"req{rid}", seg=seg):
+                    if not started:
+                        head = ("HTTP/1.1 200 OK\r\n"
+                                "Content-Type: text/event-stream\r\n"
+                                "Cache-Control: no-cache\r\n"
+                                "Connection: close\r\n\r\n")
+                        writer.write(head.encode("ascii"))
+                        started = True
+                    frame = "data: " + json.dumps(chat_chunk_body(
+                        rid, self.model, payload)) + "\n\n"
+                    writer.write(frame.encode())
+                    await self._guarded_drain(writer, rid)
+                seg += 1
                 continue
             resp: CompletionResponse = payload
             if not started:

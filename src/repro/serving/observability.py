@@ -35,15 +35,33 @@ so a sim run and a live drain produce comparable flame traces):
 plus ``feature_extract`` / ``predict`` spans when a predictor is
 attached and ``route`` instant events from the router.
 
-Everything is stdlib + numpy; nothing here imports the serving stack, so
-``core`` modules may call into it without import cycles.
+Host regions: :meth:`FlightRecorder.region` times a stretch of host work
+where it happens.  It opens a ``jax.profiler.TraceAnnotation`` named
+``clairvoyant.<name>`` (so a profiler trace shows it on the host plane,
+on the device ops' clock) and, on exit, records a span of the same name
+on the recorder's clock.  Besides the timeline's own ``prefill``,
+``decode_segment`` and admission stages, the serve path times
+``decode_poll`` / ``decode_dispatch`` / ``decode_sync`` / ``decode_emit``
+/ ``decode_stop`` (the fused-decode loop's host work, on the engine's
+worker thread) and ``dispatch`` / ``finish`` / ``sse_write`` (on the
+sidecar's event loop).
+These host regions are spans like any other, but they are not part of
+the timeline the DES shares, so :meth:`FlightRecorder.schema` leaves
+them out.  With no recorder attached a region site costs one ``is None``
+test: nothing is built, recorded or timed.
+
+Everything is stdlib + numpy (jax is imported only when a region opens);
+nothing here imports the serving stack, so ``core`` modules may call
+into it without import cycles.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import re
+import time
 from bisect import bisect_left
 from collections import defaultdict, deque
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
@@ -54,6 +72,7 @@ __all__ = [
     "Span", "FlightRecorder", "Counter", "Gauge", "Histogram",
     "MetricsRegistry", "parse_prometheus", "RankingMonitor",
     "Observability", "record_service_spans", "record_des_trace",
+    "NO_REGION", "REGION_PREFIX", "anchored_clock",
 ]
 
 
@@ -67,6 +86,15 @@ __all__ = [
 # export as Perfetto async b/e pairs.
 _ASYNC_NAMES = frozenset({"request", "queue_wait", "feature_extract",
                           "predict"})
+# The request timeline shared with the DES (``schema()``'s vocabulary);
+# every other span name is a host region of the live serve path.
+_TIMELINE_NAMES = _ASYNC_NAMES | {"prefill", "decode", "decode_segment"}
+# the spans a root ``request`` span stretches over
+_CHILD_NAMES = _TIMELINE_NAMES - {"request"}
+#: profiler name prefix of a region's TraceAnnotation
+REGION_PREFIX = "clairvoyant."
+#: what a region site enters when no recorder is attached
+NO_REGION = contextlib.nullcontext()
 
 
 class Span:
@@ -99,12 +127,16 @@ class FlightRecorder:
     locks).  The ring drops the oldest spans once ``capacity`` is
     reached and counts the drops.  Timestamps are caller-supplied
     seconds on whichever clock the drain runs (virtual for the DES and
-    sim drains, wall for the sidecar) — the recorder never reads a
-    clock, which is what lets sim and live traces share one schema.
+    sim drains, wall for the sidecar), which is what lets sim and live
+    traces share one schema.  Only :meth:`region` reads a clock: the
+    one it is given, else ``clock``, which is wall time unless the
+    owner of the timeline points it at its own (the sidecar for its
+    life, a real-engine drain for the length of each dispatch).
     """
 
     def __init__(self, capacity: int = 65536):
         self.capacity = int(capacity)
+        self.clock: Callable[[], float] = time.monotonic
         self._spans: deque = deque(maxlen=self.capacity)
         self._instants: deque = deque(maxlen=self.capacity)
         self.dropped = 0
@@ -119,9 +151,10 @@ class FlightRecorder:
         if len(buf) == buf.maxlen:
             self.dropped += 1
         buf.append((name, req_id, t0, t1, track, args))
-        le = self._last_end
-        if t1 > le.get(req_id, -math.inf):
-            le[req_id] = t1
+        if name in _CHILD_NAMES:
+            le = self._last_end
+            if t1 > le.get(req_id, -math.inf):
+                le[req_id] = t1
 
     def extend(self, spans: Iterable[tuple]) -> None:
         """Bulk append of ``(name, req_id, t0, t1, track, args)`` tuples."""
@@ -142,6 +175,17 @@ class FlightRecorder:
         if len(buf) == buf.maxlen:
             self.dropped += 1
         buf.append((name, req_id, t, track, args))
+
+    def region(self, name: str, req_id: int, track: str = "replica0", *,
+               clock: Optional[Callable[[], float]] = None,
+               **args) -> "_Region":
+        """Context manager timing host work where it happens: a
+        ``clairvoyant.<name>`` profiler annotation (with ``req_id`` and
+        ``args``) while it runs, then a span on ``clock`` (default
+        :attr:`clock`).  Call sites gate on the recorder (``NO_REGION
+        if rec is None else rec.region(...)``)."""
+        return _Region(self, name, req_id, track, args,
+                       self.clock if clock is None else clock)
 
     def request_span(self, req_id: int, t0: float, t1: float,
                      args: Optional[dict] = None) -> None:
@@ -173,8 +217,11 @@ class FlightRecorder:
                 "roots": roots, "children": children}
 
     def schema(self) -> List[str]:
-        """Sorted set of span names present — the trace's vocabulary."""
-        return sorted({tup[0] for tup in self._spans})
+        """Sorted set of the request-timeline span names present — the
+        vocabulary a live trace shares with the DES (host regions are
+        left out)."""
+        return sorted({tup[0] for tup in self._spans
+                       if tup[0] in _TIMELINE_NAMES})
 
     def clear(self) -> None:
         self._spans.clear()
@@ -189,8 +236,10 @@ class FlightRecorder:
         """Trace lifecycle invariants; returns a list of problems.
 
         * every terminal request has exactly one root ``request`` span
-          and every child span lies within the root's bounds (the trace
-          mirror of the no-lost-requests terminal gate);
+          and every child timeline span lies within the root's bounds
+          (the trace mirror of the no-lost-requests terminal gate; host
+          regions such as ``finish`` or the last ``sse_write`` end after
+          the terminal they deliver);
         * requests that finished ``ok`` carry queue_wait/prefill/decode;
         * spans on exclusive (non-async) tracks nest and never overlap.
         """
@@ -211,7 +260,7 @@ class FlightRecorder:
                 continue
             _, _, r0, r1, _, _ = roots[0]
             for name, _, t0, t1, _, _ in spans:
-                if name == "request":
+                if name == "request" or name not in _TIMELINE_NAMES:
                     continue
                 if t0 < r0 - eps or t1 > r1 + eps:
                     problems.append(
@@ -306,6 +355,50 @@ class FlightRecorder:
         with open(path, "w") as f:
             for line in self.jsonl_lines():
                 f.write(line + "\n")
+
+
+class _Region:
+    """One open :meth:`FlightRecorder.region`."""
+
+    __slots__ = ("rec", "name", "req_id", "track", "args", "clock", "ann",
+                 "t0")
+
+    def __init__(self, rec, name, req_id, track, args, clock):
+        self.rec = rec
+        self.name = name
+        self.req_id = req_id
+        self.track = track
+        self.args = args
+        self.clock = clock
+
+    def __enter__(self):
+        from jax.profiler import TraceAnnotation
+        self.ann = TraceAnnotation(REGION_PREFIX + self.name,
+                                   req_id=self.req_id, **self.args)
+        self.ann.__enter__()
+        self.t0 = self.clock()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = self.clock()
+        self.ann.__exit__(*exc)
+        self.rec.span(self.name, self.req_id, self.t0, t1, self.track,
+                      self.args or None)
+        return False
+
+
+def anchored_clock(t: float) -> Callable[[], float]:
+    """A clock on a drain's timeline for work that starts at its instant
+    ``t``: it reads ``t`` at its first call and then advances with wall
+    time."""
+    m0: List[float] = []
+
+    def clock() -> float:
+        m = time.monotonic()
+        if not m0:
+            m0.append(m)
+        return t + (m - m0[0])
+    return clock
 
 
 def record_service_spans(rec: FlightRecorder, req_id: int, *,
@@ -721,8 +814,7 @@ class Observability:
         self.recorder = recorder
         self.metrics = metrics
         self.ranking = ranking
-        self._h_ttft = self._h_sojourn = self._h_wait = None
-        self._h_tps = self._h_pred = self._h_accept = None
+        self._h_ttft = self._h_sojourn = self._h_wait = self._h_pred = None
         self._c_admit = self._c_term = None
         if metrics is not None:
             self._c_admit = metrics.counter(
@@ -737,20 +829,12 @@ class Observability:
                 "End-to-end sojourn by class")
             self._h_wait = metrics.histogram(
                 "clairvoyant_queue_wait_seconds", "Queue wait")
-            self._h_tps = metrics.histogram(
-                "clairvoyant_tokens_per_second", "Decode throughput",
-                buckets=(1, 2, 5, 10, 25, 50, 100, 250, 500, 1000,
-                         2500, 5000, 10000, 50000))
             self._h_pred = metrics.histogram(
                 "clairvoyant_predictor_latency_seconds",
                 "Per-request predictor latency (feature extraction "
                 "+ GBDT scoring)",
                 buckets=(1e-6, 5e-6, 1e-5, 2.9e-5, 5e-5, 1e-4, 5e-4,
                          1e-3, 5e-3, 0.05))
-            self._h_accept = metrics.histogram(
-                "clairvoyant_accept_rate",
-                "Speculative draft acceptance rate",
-                buckets=tuple(i / 10 for i in range(11)))
 
     @classmethod
     def default(cls, capacity: int = 65536, window: int = 512,
@@ -783,11 +867,6 @@ class Observability:
                                         klass=resp.klass or "")
                 if resp.ttft_s is not None:
                     self._h_ttft.observe(resp.ttft_s)
-                if resp.service_s > 0 and resp.tokens_generated:
-                    self._h_tps.observe(
-                        resp.tokens_generated / resp.service_s)
-                if resp.accept_rate is not None:
-                    self._h_accept.observe(resp.accept_rate)
         mon = self.ranking
         if mon is not None and resp.status == "ok" and resp.service_s > 0:
             mon.record(key=resp.p_long, observed_s=resp.service_s,

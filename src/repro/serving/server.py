@@ -80,7 +80,9 @@ from repro.serving.engine import BatchedRealEngine, RealEngine, SimEngine
 from repro.serving.faults import (CircuitBreaker, EngineCrash, FaultError,
                                   RetryPolicy, TransientBackendError,
                                   as_injector)
-from repro.serving.observability import Observability, record_service_spans
+from repro.serving.observability import (NO_REGION, Observability,
+                                         anchored_clock,
+                                         record_service_spans)
 from repro.serving.openai_api import CompletionRequest, CompletionResponse
 from repro.serving.service_time import ServiceTimeModel, sample_output_tokens
 from repro.data.tokenizer import HashTokenizer, approx_token_len
@@ -178,8 +180,9 @@ class ClairvoyantServer:
         to predictive SJF.  Never raises to the submitting client.
 
         When a flight recorder is attached, the two admission stages are
-        timed separately (feature_extract / predict spans, placed at the
-        batch's arrival instant with measured wall durations) and the
+        timed separately (``feature_extract`` / ``predict`` regions on
+        the caller's timeline: from the arrival instant ``now``, which is
+        the sidecar's clock when live, on with wall time) and the
         per-request predictor latency feeds its histogram — the paper's
         0.029 ms claim, observable on live traffic."""
         if self.predictor is None or not self.policy_obj.uses_predictor \
@@ -194,21 +197,18 @@ class ClairvoyantServer:
                     import time as _time
                     from repro.core import features as _F
                     rid = rid_hint if rid_hint is not None else self._next_id
+                    trk = None if rec is None else f"req{rid}"
+                    clk = None if rec is None else anchored_clock(now)
+                    n = len(prompts)
                     w0 = _time.perf_counter()
-                    X = _F.extract_batch(prompts)
-                    w1 = _time.perf_counter()
-                    probas = np.asarray(
-                        self.predictor.model.predict_proba(X), float)
-                    w2 = _time.perf_counter()
-                    if rec is not None:
-                        trk = f"req{rid}"
-                        rec.span("feature_extract", rid, now,
-                                 now + (w1 - w0), track=trk,
-                                 args={"batch": len(prompts)})
-                        rec.span("predict", rid, now + (w1 - w0),
-                                 now + (w2 - w0), track=trk,
-                                 args={"batch": len(prompts)})
-                    obs.observe_predict(len(prompts), w2 - w0)
+                    with NO_REGION if rec is None else rec.region(
+                            "feature_extract", rid, trk, clock=clk, batch=n):
+                        X = _F.extract_batch(prompts)
+                    with NO_REGION if rec is None else rec.region(
+                            "predict", rid, trk, clock=clk, batch=n):
+                        probas = np.asarray(
+                            self.predictor.model.predict_proba(X), float)
+                    obs.observe_predict(n, _time.perf_counter() - w0)
                 else:
                     probas = np.asarray(
                         self.predictor.proba_batch(prompts), float)
@@ -737,6 +737,9 @@ class ClairvoyantServer:
 
             if req.start is None:
                 req.start = t                 # first dispatch
+                if rec is not None:
+                    rec.span("queue_wait", req.req_id, req.arrival, t,
+                             track=f"req{req.req_id}")
             # injected transient backend error at dispatch time
             if self.faults is not None:
                 spec = self.faults.transient_due(rep.replica_id, t)
@@ -748,16 +751,13 @@ class ClairvoyantServer:
                     continue
             self._decoding[rep.replica_id] = req.req_id
             wall_gen0 = _time.monotonic()
-            seg_marks: List[float] = []
-            on_seg = None
             if rec is not None:
-                # real fused-decode segment boundaries, stamped in wall
-                # time and mapped onto the drain clock below
-                def on_seg(new_toks, _m=seg_marks):
-                    _m.append(_time.monotonic())
+                # the engine times its own prefill/decode regions, on
+                # the drain's clock from this dispatch's instant on
+                clock0, rec.clock = rec.clock, anchored_clock(t)
             try:
                 out = eng.generate(ids, max_new_tokens=n_new,
-                                   cancel_cb=cancel_cb, on_segment=on_seg)
+                                   cancel_cb=cancel_cb, req_id=req.req_id)
             except Exception as e:
                 # engine crash mid-generation (injected at a segment
                 # boundary, or organic): the popped request must not be
@@ -773,37 +773,17 @@ class ClairvoyantServer:
                 continue
             finally:
                 self._decoding.pop(rep.replica_id, None)
+                if rec is not None:
+                    rec.clock = clock0
             service = out["service_s"]
             tokens = list(resume) + list(out["tokens"])
             req.meta.setdefault("ttft_s", out["ttft_s"])
             t += service
             eng.busy_until = t
-            emit_spans = None
-            if rec is not None:
-                _t0, _t1, _ttft = t - service, t, out["ttft_s"]
-
-                def emit_spans(_a=req.arrival, _rid=req.req_id, _t0=_t0,
-                               _t1=_t1, _ttft=_ttft, _w0=wall_gen0,
-                               _marks=seg_marks):
-                    # queue_wait/prefill/decode from the attempt window;
-                    # decode_segment edges from the measured boundaries
-                    record_service_spans(rec, _rid, arrival=_a, start=_t0,
-                                         finish=_t1, ttft=_ttft,
-                                         max_segments=0, track=trk)
-                    edges = [min(_t0 + _ttft, _t1)]
-                    for m in _marks:
-                        edges.append(min(max(_t0 + (m - _w0), edges[-1]),
-                                         _t1))
-                    edges.append(_t1)
-                    for i in range(len(edges) - 1):
-                        rec.span("decode_segment", _rid, edges[i],
-                                 edges[i + 1], track=trk)
             if out.get("cancelled"):
                 if req.req_id in self._disconnected:
                     self._disconnected.discard(req.req_id)
                     req.finish = t
-                    if emit_spans is not None:
-                        emit_spans()
                     self._finish(CompletionResponse(
                         request_id=req.req_id, text="",
                         tokens_generated=len(tokens),
@@ -820,8 +800,6 @@ class ClairvoyantServer:
                     self.fault_stats["timeouts"] += 1
                     self.router.release(rep.replica_id, req)
                     req.finish = t
-                    if emit_spans is not None:
-                        emit_spans()
                     self._finish(CompletionResponse(
                         request_id=req.req_id, text="",
                         tokens_generated=len(tokens),
@@ -847,8 +825,6 @@ class ClairvoyantServer:
             self.router.on_dispatch(rep.replica_id, req, t,
                                     service_estimate=total_service)
             self.router.record_success(rep.replica_id, t)
-            if emit_spans is not None:
-                emit_spans()
             self._finish(CompletionResponse(
                 request_id=req.req_id, text=tokens_to_text(tokens),
                 tokens_generated=len(tokens),

@@ -297,6 +297,41 @@ def test_predictor_stage_spans_and_latency(small_predictor):
     assert h.count() == 8                      # per-request latencies
 
 
+def test_traced_sim_drain_times_admission_at_arrival(small_predictor):
+    """A virtual-time drain's admission regions start at each request's
+    arrival on the drain's clock, so every root span is the request's
+    sojourn and the recorder's own clock is left as it was."""
+    obs = Observability.default()
+    rec = obs.recorder
+    clock = rec.clock
+    server = ClairvoyantServer(policy="sjf", predictor=small_predictor,
+                               seed=0, observability=obs)
+    arrivals = [0.25 * i for i in range(6)]
+    for i, a in enumerate(arrivals):
+        server.submit(CompletionRequest(prompt=f"explain topic {i} " * 3),
+                      arrival=a, true_output_tokens=40 + 15 * i)
+    server.submit_many([CompletionRequest(prompt=f"burst {i}")
+                        for i in range(3)], arrivals=[2.0, 2.0, 2.0],
+                       true_output_tokens=[30, 60, 90])
+    server.drain()
+    assert rec.clock is clock
+    arrival = dict(enumerate(arrivals + [2.0] * 3, start=1))
+    timed = set()
+    for resp in server.responses:
+        rid = resp.request_id
+        by = {s.name: s for s in rec.spans_for(rid)}
+        if "feature_extract" in by:
+            fx, pr = by["feature_extract"], by["predict"]
+            assert fx.t0 == arrival[rid]
+            assert fx.t0 <= fx.t1 <= pr.t0 <= pr.t1 < arrival[rid] + 0.01
+            timed.add(rid)
+        root = by["request"]
+        assert root.t0 == arrival[rid]
+        assert root.t1 == pytest.approx(arrival[rid] + resp.sojourn_s)
+    assert timed == {1, 2, 3, 4, 5, 6, 7}     # one region per burst
+    assert rec.validate(server._terminal) == []
+
+
 @pytest.fixture(scope="module")
 def small_predictor():
     from repro.core.gbdt import GBDTParams
