@@ -255,15 +255,20 @@ def attn_block(cfg, p, x, *, mode: str, pos_offset, cache=None):
     the new KV is written at slot ``t % S`` (t = absolute fill level, RoPE
     stays absolute) so generation past the cache capacity wraps onto the
     oldest slots instead of forcing a larger allocation; while t < S this
-    is exactly the old append-at-t behavior.  ``t`` is a scalar shared by
-    the batch, or a (B,) vector of per-sequence fill levels (decode
-    lanes): each sequence then gets its own RoPE position, ring slot and
-    attention window, so one natively batched step serves lanes that
-    prefilled at different prompt lengths.  A cache carrying a block
-    table ("bt") is block-paged (serving/paging.py): "k"/"v" are shared
-    physical pools (n_pages, page, KV, hd) and each lane reads/writes its
-    logical window through its table row; unallocated slots point at the
-    pinned trash page 0 and dead lanes past the window write there.
+    is exactly the old append-at-t behavior.  ``t`` is a (B,) vector of
+    per-sequence fill levels (decode lanes): each sequence then gets its
+    own RoPE position, ring slot and attention window, so one natively
+    batched step serves lanes that prefilled at different prompt lengths.
+    Or ``t`` is a scalar shared by the batch (the serial path), in
+    run_stack's carry form, marked by a "layer" index: "k"/"v" are the
+    whole layer stack (repeats, B, S, KV, hd), the new row is written
+    into it in place at (layer, 0, t % S, 0, 0), and the layer's ring is
+    attended straight out of the stack; the returned cache keeps that
+    form.  A cache carrying a block table ("bt") is block-paged
+    (serving/paging.py): "k"/"v" are shared physical pools
+    (n_pages, page, KV, hd) and each lane reads/writes its logical window
+    through its table row; unallocated slots point at the pinned trash
+    page 0 and dead lanes past the window write there.
     """
     B = x.shape[0]
     h = rmsnorm(x, p["norm"], cfg.norm_eps)
@@ -402,28 +407,39 @@ def attn_block(cfg, p, x, *, mode: str, pos_offset, cache=None):
         return x + dense(out, p["wo"]), new_cache
     else:  # decode
         t = cache["t"]  # absolute fill level(s); () shared or (B,) per-seq
-        S = cache["k"].shape[1]
+        S = cache["k"].shape[-3]
         per_seq = jnp.ndim(t) != 0
         positions = t[:, None] if per_seq else jnp.full((1,), t, jnp.int32)
         q, k, v = _project_qkv(cfg, p, h, positions)
+        k, v = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
         slot = jax.lax.rem(t, jnp.int32(S))
-        if per_seq:
+        if "layer" in cache:
+            # run_stack's carry form: "k"/"v" are the whole layer stack
+            # (repeats, B, S, KV, hd).  Write the new row into it in place,
+            # then attend this layer's ring read straight out of the
+            # updated stack: XLA fuses that read into the attention dots,
+            # so no layer's ring is copied out or written back.
+            layer = cache["layer"]
+            at = (layer, 0, slot, 0, 0)
+            k_all = jax.lax.dynamic_update_slice(cache["k"], k[None], at)
+            v_all = jax.lax.dynamic_update_slice(cache["v"], v[None], at)
+            ck = jax.lax.dynamic_index_in_dim(k_all, layer, keepdims=False)
+            cv = jax.lax.dynamic_index_in_dim(v_all, layer, keepdims=False)
+        else:
             # per-sequence ring write as a one-hot select: XLA CPU lowers
             # batched scatters to a slow generic loop, but this select
             # vectorizes (it streams the cache once, which decode does
             # anyway for the attention reads)
             hit = (jnp.arange(S)[None, :] == slot[:, None])[..., None, None]
-            ck = jnp.where(hit, k.astype(cache["k"].dtype)[:, :1], cache["k"])
-            cv = jnp.where(hit, v.astype(cache["v"].dtype)[:, :1], cache["v"])
-        else:
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
+            ck = jnp.where(hit, k[:, :1], cache["k"])
+            cv = jnp.where(hit, v[:, :1], cache["v"])
         ck = constrain(ck, "batch", "kv_seq", "kv_heads", "head_dim")
         cv = constrain(cv, "batch", "kv_seq", "kv_heads", "head_dim")
         out = decode_attention(q, ck, cv, t)
-        new_cache = {"k": ck, "v": cv, "t": t + 1}
+        if "layer" in cache:
+            new_cache = {"k": k_all, "v": v_all, "t": t + 1, "layer": layer}
+        else:
+            new_cache = {"k": ck, "v": cv, "t": t + 1}
     out = constrain(out, "batch", "seq", "heads", "head_dim")
     out = out.reshape(B, -1, cfg.attn_dim)
     return x + dense(out, p["wo"]), new_cache

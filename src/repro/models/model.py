@@ -273,6 +273,11 @@ class LM:
         reallocated a larger cache when generation approaches the buffer
         end — capacity bounds the attention window, not the output length.
         ``t`` is the absolute fill level (RoPE positions stay absolute).
+        With one fill level per layer (``t`` of shape (repeats,), the
+        serial path), decode carries the stacked K/V through the layer
+        scan, writes one row per layer in place and attends each layer's
+        ring from the stack; per-lane levels (repeats, B) re-emit each
+        layer's ring.
         """
         cfg = self.cfg
         dt = dtype_of(cfg)

@@ -5,7 +5,10 @@ the per-position params stacked on a leading axis, so HLO size and compile
 time are O(pattern length), not O(depth) — essential for lowering 40
 (arch x shape) dry-run cells on 512 devices, and the production choice anyway.
 Caches ride along as scan xs/ys: prefill emits per-repeat caches as ys,
-decode consumes and re-emits them.
+and decode consumes and re-emits them, except for a ring-attention cache
+with one scalar fill level per layer (the serial decode path): its
+stacked K/V ride the scan's carry instead, and each layer writes only its
+new row into the stack, in place, and attends its ring from the stack.
 """
 
 from __future__ import annotations
@@ -110,23 +113,59 @@ def run_stack(cfg, blocks_params, x, *, mode: str, caches=None,
         block_fn = jax.checkpoint(block_fn, prevent_cse=False,
                                   static_argnums=(0,))
 
+    # Serial decode's ring caches ride the carry: returned as ys, each
+    # layer's whole ring would be written back into the stack to change
+    # one row.  Chosen by what the cache holds, never by a flag.
+    in_carry = ([False] * len(pattern) if caches is None else
+                [_ring_in_carry(mode, kind, c)
+                 for kind, c in zip(pattern, caches)])
+    rings = layers = None
+    if any(in_carry):
+        rings = tuple((c["k"], c["v"]) if r else None
+                      for r, c in zip(in_carry, caches))
+        caches = tuple({"t": c["t"]} if r else c
+                       for r, c in zip(in_carry, caches))
+        layers = jnp.arange(cfg.pattern_repeats, dtype=jnp.int32)
+
     def body(carry, xs):
-        x, aux = carry
-        blk_params, blk_caches = xs
+        x, aux, rings = carry
+        blk_params, blk_caches, layer = xs
         x = constrain(x, "batch", "seq_sp", "embed")
-        new_caches = []
+        new_caches, new_rings = [], []
         for pos, kind in enumerate(pattern):
             c = None if blk_caches is None else blk_caches[pos]
+            if in_carry[pos]:
+                c = {"k": rings[pos][0], "v": rings[pos][1], "t": c["t"],
+                     "layer": layer}
             x, nc, a = block_fn(kind, blk_params[pos], x, c)
+            if in_carry[pos]:
+                new_rings.append((nc["k"], nc["v"]))
+                nc = {"t": nc["t"]}
+            else:
+                new_rings.append(None)
             new_caches.append(nc)
             aux = aux + a
-        return (x, aux), tuple(new_caches)
+        return (x, aux, None if rings is None else tuple(new_rings)), \
+            tuple(new_caches)
 
     if mode == "train" and remat:
         body = jax.checkpoint(body, prevent_cse=False)
 
     aux0 = jnp.zeros((), jnp.float32)
-    (x, aux), new_caches = jax.lax.scan(
-        body, (x, aux0), (blocks_params, caches),
+    (x, aux, rings), new_caches = jax.lax.scan(
+        body, (x, aux0, rings), (blocks_params, caches, layers),
         unroll=min(SCAN_UNROLL["n"], cfg.pattern_repeats))
+    if rings is not None:
+        new_caches = tuple(
+            c if r is None else {"k": r[0], "v": r[1], "t": c["t"]}
+            for r, c in zip(rings, new_caches))
     return x, new_caches, aux
+
+
+def _ring_in_carry(mode: str, kind: str, cache) -> bool:
+    """A decode-mode ring-attention cache with one scalar fill level per
+    layer: exactly "k", "v" and a ``t`` of shape (repeats,).  Per-lane
+    fill levels (repeats, B), block-paged caches ("bt") and every other
+    mode or block keep the xs/ys path."""
+    return (mode == "decode" and kind in (ATTN, ATTN_MOE)
+            and set(cache) == {"k", "v", "t"} and jnp.ndim(cache["t"]) == 1)

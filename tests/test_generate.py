@@ -2,6 +2,8 @@
 per-token loop, ring-buffer KV cache semantics, bucketed prefill, and
 mid-generation cancellation (PR 3 tentpole)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -145,6 +147,66 @@ def test_ring_decode_attends_window_only():
     out_full = decode_attention(q, k, v, jnp.asarray(S - 1, jnp.int32))
     np.testing.assert_allclose(np.asarray(out_wrapped), np.asarray(out_full),
                                atol=1e-6)
+
+
+@pytest.mark.parametrize("arch,plen,steps", [
+    ("smollm-360m", 1, 9),               # slots 1..7 (S-1), wraps to 0, 1
+    ("smollm-360m", 7, 2),               # the last slot S-1, then slot 0
+    ("llama4-maverick-400b-a17b", 3, 6),  # two attention positions
+    ("jamba-v0.1-52b", 2, 7),            # one attention among mamba blocks
+])
+def test_carried_ring_decode_matches_per_lane_path(arch, plen, steps):
+    """Serial decode carries the stacked ring through the layer scan and
+    writes one row per layer; the per-lane path (``t`` (repeats, 1))
+    still emits whole rings as scan ys.  From one prefilled cache both
+    give bitwise-equal logits and K/V, and the carried write touches
+    slot ``t % S`` alone in every layer."""
+    S = 8
+    base = get_config(arch).reduced()
+    cfg = dataclasses.replace(base, num_layers=3 * len(base.block_pattern))
+    lm = LM(cfg)
+    params = lm.init(jax.random.key(1))
+    rng = np.random.default_rng(plen)
+    ids = jnp.asarray(rng.integers(0, cfg.vocab_size, plen), jnp.int32)
+    logits, carried = lm.prefill(params, {"tokens": ids[None]}, pad_to=S)
+    rings = [i for i, c in enumerate(carried) if "t" in c]
+    assert rings and all(carried[i]["t"].shape == (cfg.pattern_repeats,)
+                         for i in rings)
+    lanes = tuple({**c, "t": c["t"][:, None]} if "t" in c else c
+                  for c in carried)
+    tok = int(np.argmax(np.asarray(logits)[0]))
+    # only the carried path writes into the whole (repeats, B, S, ...) stack
+    stack_write = "[{}] = dynamic_update_slice".format(
+        ",".join(map(str, carried[rings[0]]["k"].shape)))
+    batch = {"tokens": jnp.full((1, 1), tok, jnp.int32)}
+    assert stack_write in str(jax.make_jaxpr(lm.decode_step)(
+        params, carried, batch))
+    assert stack_write not in str(jax.make_jaxpr(lm.decode_step)(
+        params, lanes, batch))
+    step = jax.jit(lm.decode_step)
+    for _ in range(steps):
+        t = int(np.asarray(carried[rings[0]]["t"])[0])
+        before = [np.asarray(carried[i][kv]) for i in rings for kv in "kv"]
+        batch = {"tokens": jnp.full((1, 1), tok, jnp.int32)}
+        logits_c, carried = step(params, carried, batch)
+        logits_l, lanes = step(params, lanes, batch)
+        np.testing.assert_array_equal(np.asarray(logits_c),
+                                      np.asarray(logits_l))
+        after = [np.asarray(carried[i][kv]) for i in rings for kv in "kv"]
+        for i in rings:
+            assert carried[i]["t"].shape == (cfg.pattern_repeats,)
+            np.testing.assert_array_equal(np.asarray(carried[i]["t"]), t + 1)
+            for kv in "kv":
+                np.testing.assert_array_equal(np.asarray(carried[i][kv]),
+                                              np.asarray(lanes[i][kv]))
+        others = np.arange(S) != t % S
+        for old, new in zip(before, after):
+            assert new.shape == old.shape and new.dtype == old.dtype
+            np.testing.assert_array_equal(new[:, :, others], old[:, :, others])
+            for layer in range(cfg.pattern_repeats):
+                assert not np.array_equal(new[layer, :, t % S],
+                                          old[layer, :, t % S])
+        tok = int(np.argmax(np.asarray(logits_c)[0]))
 
 
 # ----------------------------------------------------------------- bucketing

@@ -8,6 +8,7 @@ group 3), the bucket-512 prefill, a ``FusedDecoder`` segment and a 4-lane
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,16 +121,70 @@ def test_prefill_compiles(one_chip, lm):
     _fits(compiled)
 
 
-def test_fused_segment_compiles(one_chip, lm):
+@pytest.fixture(scope="module")
+def fused_segment(one_chip, lm):
     from repro.serving.generate import FusedDecoder
     params = _spec(lm.abstract_params()[0], one_chip)
     caches = _spec(jax.eval_shape(lambda: lm.init_cache(1, MAX_LEN)),
                    one_chip)
     i32 = _scalar(jnp.int32, one_chip)
     dec = FusedDecoder(lm, MAX_LEN, SEGMENT_LEN)
-    compiled = dec._segment.lower(params, caches, i32, i32, i32, i32,
-                                  i32).compile()
-    _fits(compiled)
+    return dec._segment.lower(params, caches, i32, i32, i32, i32,
+                              i32).compile()
+
+
+def test_fused_segment_compiles(fused_segment):
+    _fits(fused_segment)
+
+
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = \w+\[([\d,]*)\]"
+                        r"(?:\{[^}]*\})?\s+([\w\-]+)\(([^)]*)\)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+) .*\{\s*$")
+
+
+def test_fused_segment_writes_one_row_per_layer(fused_segment, lm):
+    """Serial decode carries the stacked K/V ring through the layer scan:
+    each layer writes its one new row into the stack in place and attends
+    its ring straight out of the stack.  No instruction writes a layer's
+    whole ring back into the stack, none copies a layer's ring out of it
+    or the stack to another layout, and the scratch memory is no larger
+    than the whole-ring write-back's (215,345,152 bytes)."""
+    cfg = lm.cfg
+    stack = (cfg.pattern_repeats, 1, MAX_LEN, cfg.num_kv_heads, cfg.head_dim)
+    row = (1, 1, 1, cfg.num_kv_heads, cfg.head_dim)
+    shapes, instrs, fused = {}, [], set()
+    computation = None
+    for line in fused_segment.as_text().splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            computation = m.group(1)
+            continue
+        if " fusion(" in line:
+            fused.update(re.findall(r"calls=%([\w.\-]+)", line))
+        m = _HLO_INSTR.match(line)
+        if m:
+            name, dims, op, args = m.groups()
+            shapes[name] = tuple(int(d) for d in dims.split(",") if d)
+            instrs.append((computation, name, op,
+                           re.findall(r"%([\w.\-]+)", args)))
+    row_writes = 0
+    for computation, name, op, args in instrs:
+        if (computation not in fused and shapes[name] != stack
+                and MAX_LEN in shapes[name]):
+            assert not any(shapes.get(a) == stack for a in args), (
+                f"{name} ({op}) copies a layer's ring out of the stack")
+        if shapes[name] != stack:
+            continue
+        assert op != "copy", f"{name} copies the stacked cache"
+        for a in args:
+            s = shapes.get(a, ())
+            assert MAX_LEN not in s or s == stack, (
+                f"{name} ({op}) writes {a} {s} into the stacked cache")
+        if op == "dynamic-update-slice":
+            assert shapes[args[1]] == row, (name, shapes[args[1]])
+            row_writes += 1
+    assert row_writes == 2                     # K and V
+    assert fused_segment.memory_analysis().temp_size_in_bytes <= 215_345_152
 
 
 def test_paged_lane_segment_compiles(one_chip, lm):
