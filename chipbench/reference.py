@@ -1,17 +1,15 @@
-"""Plain float32 reference of a llama-architecture decoder, and its control.
+"""Plain float32 reference of a decoder, and its control.
 
-Follows the Hugging Face ``LlamaForCausalLM`` description: token embedding;
-per layer, pre-RMSNorm grouped-query attention with rotary positions
-(``rotate_half`` form, base ``rope_theta``), causal softmax, output
-projection and residual, then pre-RMSNorm SwiGLU MLP and residual; a final
-RMSNorm and the output head (the embedding's transpose when tied).  It
-imports nothing of the program; its weights come from ``weights.py`` and
-the seed, and its tokens from :func:`tokenize`, a copy of the served
-tokenizer's word hash.
+Token embedding; the layers of the configuration's family
+(``chipbench/families/<name>.py``: ``reference_weights`` and
+``reference_layer``), one at a time over every sequence, so that only one
+layer's weights are on the device at once; a final RMSNorm and the output
+head (the embedding's transpose when tied).  It imports nothing of the
+program; its weights come from ``weights.py``, the family and the seed,
+and its tokens from :func:`tokenize`, a copy of the served tokenizer's
+word hash.
 
 Every matrix product runs under ``jax.default_matmul_precision("highest")``.
-The layers run one at a time over every sequence, so that only one layer's
-weights are on the device at once.
 
 ``quant=True`` is the control: the same forward with the inputs of every
 projection (weights per output column, activations per row) rounded to
@@ -22,13 +20,13 @@ bfloat16.  Attention scores and norms stay in float32.
 from __future__ import annotations
 
 import functools
-from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.weights import embed, final_norm, head, layer_weights, seed_words
+from chipbench import families
+from chipbench.weights import embed, final_norm, head, seed_words
 
 _HASH_SEED = 1234567891
 _PAD = 128           # sequences are padded to a multiple of this length
@@ -46,22 +44,6 @@ def tokenize(text: str, vocab: int) -> np.ndarray:
     return np.asarray(ids, np.int32)
 
 
-@dataclass(frozen=True)
-class Dims:
-    hidden: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    eps: float
-    theta: float
-
-    @classmethod
-    def of(cls, c: dict) -> "Dims":
-        return cls(c["hidden_size"], c["num_attention_heads"],
-                   c["num_key_value_heads"], c["head_dim"],
-                   float(c["rms_norm_eps"]), float(c["rope_theta"]))
-
-
 def _fp8(x, axis):
     """Round to float8 e4m3 with a scale per slice along ``axis``."""
     s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / FP8_MAX
@@ -77,44 +59,6 @@ def _mm(x, w, quant: bool):
 
 def _rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
-
-
-def _rope(x, theta):
-    """x: (S, heads, head_dim), positions 0..S-1."""
-    s, _, hd = x.shape
-    half = hd // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv      # (S, half)
-    cos = jnp.cos(ang)[:, None, :]
-    sin = jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-@functools.partial(jax.jit, static_argnames=("d", "quant"))
-def _layer(x, w, d: Dims, quant: bool):
-    s = x.shape[0]
-    h = _rms(x, w["attn_norm"], d.eps)
-    q = _mm(h, w["wq"], quant).reshape(s, d.heads, d.head_dim)
-    k = _mm(h, w["wk"], quant).reshape(s, d.kv_heads, d.head_dim)
-    v = _mm(h, w["wv"], quant).reshape(s, d.kv_heads, d.head_dim)
-    q, k = _rope(q, d.theta), _rope(k, d.theta)
-    rep = d.heads // d.kv_heads          # query head j reads kv head j//rep
-    k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
-    sc = jnp.einsum("qhd,khd->hqk", q, k) * d.head_dim ** -0.5
-    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
-    p = jax.nn.softmax(jnp.where(causal[None], sc, -jnp.inf), axis=-1)
-    o = jnp.einsum("hqk,khd->qhd", p, v).reshape(s, d.heads * d.head_dim)
-    x = x + _mm(o, w["wo"], quant)
-    h = _rms(x, w["mlp_norm"], d.eps)
-    g = jax.nn.silu(_mm(h, w["w_gate"], quant)) * _mm(h, w["w_up"], quant)
-    return x + _mm(g, w["w_down"], quant)
-
-
-@functools.partial(jax.jit, static_argnames=("c_items",))
-def _layer_w(words, layer, c_items):
-    w = layer_weights(dict(c_items), words, layer)
-    return {k: v.astype(jnp.float32) for k, v in w.items()}
 
 
 @functools.partial(jax.jit, static_argnames=("c_items",))
@@ -145,12 +89,11 @@ def _control_gap(words, x, xq, c_items):
     return ref.max(-1) - jnp.take_along_axis(ref, pick[:, None], 1)[:, 0]
 
 
-def _items(c: dict) -> tuple:
-    keys = ("hidden_size", "intermediate_size", "num_attention_heads",
-            "num_key_value_heads", "head_dim", "vocab_size",
-            "tie_word_embeddings", "rms_norm_eps", "rope_theta",
-            "num_hidden_layers")
-    return tuple((k, c[k]) for k in keys)
+def static(c: dict) -> tuple:
+    """The scalar keys of configuration ``c``, hashable: a static argument
+    of a jitted function, which rebuilds the dict with ``dict(...)``."""
+    return tuple(sorted((k, v) for k, v in c.items()
+                        if isinstance(v, (bool, int, float, str))))
 
 
 def served_gaps(c: dict, seed: int, seqs: list, control: bool = False):
@@ -162,7 +105,7 @@ def served_gaps(c: dict, seed: int, seqs: list, control: bool = False):
     same gaps for the token the float8 control puts first at each
     position.
     """
-    items, d = _items(c), Dims.of(c)
+    items, fam = static(c), families.of(c)
     words = jnp.asarray(seed_words(seed))
     xs, xq, spans = [], [], []
     for prompt, served in seqs:
@@ -180,10 +123,10 @@ def served_gaps(c: dict, seed: int, seqs: list, control: bool = False):
         spans.append((len(prompt) - 1, n - 1, jnp.asarray(targets)))
     with jax.default_matmul_precision("highest"):
         for layer in range(c["num_hidden_layers"]):
-            w = _layer_w(words, layer, items)
-            xs = [_layer(x, w, d, False) for x in xs]
+            w = fam.reference_weights(c, words, layer)
+            xs = [fam.reference_layer(c, layer, x, w, False) for x in xs]
             if control:
-                xq = [_layer(x, w, d, True) for x in xq]
+                xq = [fam.reference_layer(c, layer, x, w, True) for x in xq]
             del w
         gaps, ctrl = [], []
         for x, q, (lo, hi, targets) in zip(xs, xq, spans):

@@ -22,6 +22,7 @@ JSON.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import json
 import os
@@ -130,6 +131,8 @@ def segment_gaps_ns(tr, regions: list) -> list:
         return []
     mods = tr.modules[first]
     ops = tr.ops[first]
+    op_starts = [o.start for o in ops]
+    longest = max((o.dur for o in ops), default=0.0)
     by_req = defaultdict(list)
     for r in regions:
         if r.name == SEGMENT and t0 <= r.start < t1:
@@ -142,7 +145,10 @@ def segment_gaps_ns(tr, regions: list) -> list:
             if not ends or not starts or min(starts) < max(ends):
                 continue
             a, b = max(ends), min(starts)
-            busy = sum(e - s for s, e in trace_reduce.union(ops, a, b))
+            # only ops that start before b and may still run at a
+            near = ops[bisect.bisect_left(op_starts, a - longest):
+                       bisect.bisect_left(op_starts, b)]
+            busy = sum(e - s for s, e in trace_reduce.union(near, a, b))
             gaps.append(b - a - busy)
     return gaps
 

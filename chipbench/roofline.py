@@ -1,17 +1,19 @@
-"""Operations and bytes that a llama-architecture step needs, from shapes.
+"""Operations and bytes that a step needs, from shapes, and the chip's peaks.
 
-``c`` is a configuration file's dict (Hugging Face key names).  Weights
-and the K/V cache are bfloat16 (2 bytes).  The bytes are what the
-algorithm needs: every weight once per step (the embedding table only for
-the rows looked up, unless it doubles as the output head), and the K/V of
-the positions a row actually attends.  A path that reads less cannot push
-a share of the roofline over 100%.
+``c`` is a configuration file's dict (Hugging Face key names).  The counts
+are the family's (``chipbench/families/<name>.py``), from the args that
+the harness's ``chipbench.segment`` and ``chipbench.prefill`` marks
+record.  Weights and the K/V cache are bfloat16 (``BYTES``).  The bytes
+are what the algorithm needs, so a path that reads less cannot push a
+share of the roofline over 100%.
 """
 
 from __future__ import annotations
 
 import json
 import os
+
+from chipbench import families
 
 BYTES = 2          # bfloat16
 
@@ -30,61 +32,20 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def layer_matmul_params(c: dict) -> int:
-    d, f = c["hidden_size"], c["intermediate_size"]
-    qd = c["num_attention_heads"] * c["head_dim"]
-    kd = c["num_key_value_heads"] * c["head_dim"]
-    return d * (qd + 2 * kd) + qd * d + 3 * d * f
+def decode_steps(c: dict, args: dict) -> list:
+    """``(flops, bytes)`` of each decode step of a ``chipbench.segment``
+    mark with ``args``."""
+    return families.of(c).decode_steps(c, args)
 
 
-def matmul_params(c: dict) -> int:
-    """Parameters of every layer's projections (no embedding, no head)."""
-    return c["num_hidden_layers"] * layer_matmul_params(c)
-
-
-def head_params(c: dict) -> int:
-    return c["hidden_size"] * c["vocab_size"]
-
-
-def norm_params(c: dict) -> int:
-    return (2 * c["num_hidden_layers"] + 1) * c["hidden_size"]
+def prefill(c: dict, args: dict) -> int:
+    """Operations of the prefill of a ``chipbench.prefill`` mark."""
+    return families.of(c).prefill(c, args)
 
 
 def param_count(c: dict) -> int:
-    """Every parameter held: layers, norms, embedding, and an untied head."""
-    n = matmul_params(c) + norm_params(c) + head_params(c)
-    return n + (0 if c["tie_word_embeddings"] else head_params(c))
-
-
-def kv_bytes_per_position(c: dict) -> int:
-    return (c["num_hidden_layers"] * 2 * c["num_key_value_heads"]
-            * c["head_dim"] * BYTES)
-
-
-def attn_flops_per_position(c: dict) -> int:
-    """Scores and weighted values of one query against one key, over every
-    layer and head: two multiply-adds per head dimension."""
-    return (4 * c["num_hidden_layers"] * c["num_attention_heads"]
-            * c["head_dim"])
-
-
-def decode_step(c: dict, fills) -> tuple:
-    """``(flops, bytes)`` of one decode step over rows that attend
-    ``fills[i]`` positions each (the new token's included)."""
-    rows = len(fills)
-    flops = rows * 2 * (matmul_params(c) + head_params(c)) \
-        + attn_flops_per_position(c) * sum(fills)
-    weights = (matmul_params(c) + norm_params(c) + head_params(c)) * BYTES
-    lookups = rows * c["hidden_size"] * BYTES
-    return flops, weights + lookups + kv_bytes_per_position(c) * sum(fills)
-
-
-def prefill(c: dict, n: int) -> int:
-    """Operations to prefill an ``n``-token prompt: every token through
-    every layer, causal attention, and the head at the last position."""
-    return (2 * matmul_params(c) * n
-            + attn_flops_per_position(c) * n * (n + 1) // 2
-            + 2 * head_params(c))
+    """Every parameter the served model holds."""
+    return families.of(c).param_count(c)
 
 
 def least_time(flops: float, nbytes: float, peak: dict) -> float:

@@ -92,7 +92,10 @@ def load_json(path: str) -> dict:
 
 
 def load_cell(name: str) -> tuple:
-    """(BENCHMARK.json, the cell, its configuration, its traffic mix)."""
+    """(BENCHMARK.json, the cell, its configuration, its traffic mix).
+    Exits non-zero where the configuration's architecture has no family
+    module."""
+    from chipbench import families
     bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -100,6 +103,10 @@ def load_cell(name: str) -> tuple:
     cell = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     c = load_json(os.path.join(ROOT, conf["file"]))
+    try:
+        families.of(c)
+    except families.UnknownArchitecture as e:
+        raise SystemExit(f"chipbench: {e}") from None
     mix = load_json(os.path.join(HERE, "traffic", f"{cell['traffic']}.json"))
     return bench, cell, c, mix
 
@@ -244,10 +251,11 @@ def run_cell(cell: dict, c: dict, mix: dict, metrics: list, seed: int,
     (tests)."""
     import jax
 
-    from chipbench import checks, reference, roofline, stack, stats
-    from chipbench import trace_reduce, traffic_gen
+    from chipbench import checks, families, reference, roofline, stack
+    from chipbench import stats, trace_reduce, traffic_gen
     from chipbench.traffic_gen import prompt_tokens
 
+    family = families.of(c)      # an unknown architecture fails here
     devs = device_check(cell["chips"]) if require_tpu else jax.devices()
     meter = CompileMeter().install()
     phases = {"jax_init_s": time.monotonic() - T_START}
@@ -257,7 +265,7 @@ def run_cell(cell: dict, c: dict, mix: dict, metrics: list, seed: int,
     peak = roofline.peaks(device["kind"]) if require_tpu else None
 
     t = time.monotonic()
-    cfg = stack.arch_config(c)
+    cfg = family.arch_config(c)
     params = stack.make_params(c, cfg, seed)
     phases["weights_s"] = time.monotonic() - t
     t = time.monotonic()

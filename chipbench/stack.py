@@ -6,8 +6,9 @@ on the mix's profile, tau calibrated from the model's short and long
 service, one ``InProcessBackend`` over a ``RealEngine``, a
 ``ClairvoyantServer`` with sojourn deadlines and a ``Sidecar`` on
 loopback.  Two things differ: the architecture comes from a configuration
-file (``build_sidecar`` takes only a registered name), and the weights are
-the benchmark's own, made from the seed (``weights.py``).
+file (``build_sidecar`` takes only a registered name), checked by the
+file's family (``chipbench/families/``), and the weights are the
+benchmark's own, made by the family from the seed.
 
 The harness puts its own spans around the program's layers here, without
 changing them: ``chipbench.admission`` around the predictor call,
@@ -21,13 +22,11 @@ check reads.
 
 from __future__ import annotations
 
-import dataclasses
-import functools
 from dataclasses import dataclass, field
 
 import jax
 
-from chipbench import weights
+from chipbench import families
 
 
 @dataclass
@@ -37,34 +36,11 @@ class Probe:
     gen: dict = field(default_factory=dict)     # the request in service
 
 
-def arch_config(c: dict):
-    """The program's ``ArchConfig`` for configuration file ``c``, checked
-    against the file's published sizes."""
-    from repro.configs import get_config
-    cfg = dataclasses.replace(get_config(c["arch"]), **c["overrides"])
-    want = {"num_layers": c["num_hidden_layers"],
-            "d_model": c["hidden_size"],
-            "num_heads": c["num_attention_heads"],
-            "num_kv_heads": c["num_key_value_heads"],
-            "head_dim": c["head_dim"], "d_ff": c["intermediate_size"],
-            "vocab_size": c["vocab_size"],
-            "tie_embeddings": c["tie_word_embeddings"],
-            "rope_theta": c["rope_theta"], "norm_eps": c["rms_norm_eps"],
-            "dtype": c["torch_dtype"], "block_pattern": ("attn",),
-            "mlp_activation": "silu", "qk_norm": False}
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        bad = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
-        raise ValueError(f"program config differs from the file: {bad}")
-    return cfg
-
-
 def make_params(c: dict, cfg, seed: int):
-    """The served weights, made on the device in one jitted call, checked
+    """The served weights, made on the device by the family, checked
     against the program's own parameter layout."""
     from repro.models.model import LM
-    params = jax.jit(functools.partial(weights.program_params, c))(
-        weights.seed_words(seed))
+    params = families.of(c).make_params(c, seed)
     want, _ = LM(cfg).abstract_params()
     if jax.tree.structure(want) != jax.tree.structure(params) or any(
             (a.shape, a.dtype) != (b.shape, b.dtype) for a, b in

@@ -10,16 +10,17 @@ import numpy as np
 import pytest
 
 from chipbench import reference, weights
+from chipbench.families import llama
 
 SEED = 2**40 + 12345
 
 
 def toy(tied: bool) -> dict:
-    return {"hidden_size": 64, "intermediate_size": 128,
-            "num_attention_heads": 4, "num_key_value_heads": 2,
-            "head_dim": 16, "vocab_size": 512, "tie_word_embeddings": tied,
-            "rms_norm_eps": 1e-5, "rope_theta": 10000.0,
-            "num_hidden_layers": 3}
+    return {"architectures": ["LlamaForCausalLM"], "hidden_size": 64,
+            "intermediate_size": 128, "num_attention_heads": 4,
+            "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 512,
+            "tie_word_embeddings": tied, "rms_norm_eps": 1e-5,
+            "rope_theta": 10000.0, "num_hidden_layers": 3}
 
 
 def program_logits(c, toks):
@@ -30,7 +31,7 @@ def program_logits(c, toks):
         num_kv_heads=2, head_dim=16, d_ff=128, vocab_size=512,
         dtype="float32", tie_embeddings=c["tie_word_embeddings"])
     lm = LM(cfg)
-    p = jax.jit(lambda w: weights.program_params(c, w))(
+    p = jax.jit(lambda w: llama.program_params(c, w))(
         weights.seed_words(SEED))
     want, _ = lm.abstract_params()
     assert jax.tree.structure(want) == jax.tree.structure(p)
@@ -57,8 +58,8 @@ def test_reference_gaps_equal_the_program_forward(tied):
 def test_layer_weights_do_not_depend_on_how_many_are_made():
     c = toy(True)
     w = weights.seed_words(SEED)
-    stacked = jax.jit(lambda w: weights.program_params(c, w))(w)
-    one = jax.jit(lambda w: weights.layer_weights(c, w, 2))(w)
+    stacked = jax.jit(lambda w: llama.program_params(c, w))(w)
+    one = jax.jit(lambda w: llama.layer_weights(c, w, 2))(w)
     att = stacked["blocks"][0]["attn"]
     assert bool(jnp.all(one["wq"] == att["wq"][2]))
     assert bool(jnp.all(one["attn_norm"] == att["norm"][2]))

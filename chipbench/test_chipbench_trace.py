@@ -70,6 +70,27 @@ def test_device_time_is_attributed_to_the_preceding_span():
         ("chipbench.segment", 300.0 * U), ("chipbench.prefill", 100.0 * U)]
 
 
+def test_a_program_that_seems_to_start_before_its_span_is_its_spans():
+    # as on the chip: each program is seen 1.5 units before the span whose
+    # call launched it starts, and ends before the next span starts
+    mods = [Interval("jit__lambda(1)", 1 * U, 3 * U),
+            Interval("jit__segment_impl(2)", 8.5 * U, 36 * U),
+            Interval("jit__segment_impl(2)", 38.5 * U, 45 * U)]
+    marks = [Interval("chipbench.window", 0, 100 * U),
+             Interval("chipbench.prefill", 2.5 * U, 4 * U, {"tokens": 3}),
+             Interval("chipbench.segment", 10 * U, 10.5 * U,
+                      {"plen": 3, "first_step": 0, "steps": 16}),
+             Interval("chipbench.segment", 40 * U, 40.5 * U,
+                      {"plen": 3, "first_step": 16, "steps": 4})]
+    t = Trace({"/device:TPU:0": mods}, {"/device:TPU:0": mods}, marks)
+    got = tr.device_time_by_mark(t, ("chipbench.prefill",
+                                     "chipbench.segment"))
+    assert [(m.args, ns) for m, ns in got] == [
+        ({"tokens": 3}, 2.0 * U),
+        ({"plen": 3, "first_step": 0, "steps": 16}, 27.5 * U),
+        ({"plen": 3, "first_step": 16, "steps": 4}, 6.5 * U)]
+
+
 def test_a_program_cut_by_the_window_is_left_out():
     t = synthetic()
     t.marks[0] = Interval("chipbench.window", 0, 680 * U)

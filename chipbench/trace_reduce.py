@@ -210,9 +210,16 @@ def reduce(tr: Trace) -> dict:
 
 def device_time_by_mark(tr: Trace, kinds: tuple) -> list:
     """``(mark, device ns)`` for each harness span of the given names that
-    started in the window: the programs that started on the first device
-    after it and before the next such span, and ended inside the window.
-    A span whose programs were cut by the window's edge is left out."""
+    started in the window: the programs on the first device that ended
+    after it started and before the next such span started, and ended
+    inside the window.  A span whose programs were cut by the window's
+    edge is left out.
+
+    A program is placed by its end, not its start: on the TPU the device's
+    clock in the trace runs ahead of the host's by a millisecond or two,
+    so a program can seem to start before the span whose call launched it,
+    while it still ends before the host, which waits for its result,
+    starts the next span."""
     t0, t1 = window(tr)
     devices = sorted(tr.modules)
     if not devices:
@@ -223,7 +230,7 @@ def device_time_by_mark(tr: Trace, kinds: tuple) -> list:
     time = defaultdict(float)
     cut = set()
     for mod in mods:
-        k = bisect.bisect_right(starts, mod.start) - 1
+        k = bisect.bisect_right(starts, mod.end) - 1
         if k < 0:
             continue
         if mod.end > t1:
