@@ -76,6 +76,7 @@ class ArchConfig:
     # MLP / attention details
     mlp_activation: str = "silu"  # silu (SwiGLU) | gelu (GeGLU)
     qk_norm: bool = False
+    use_rope: bool = True        # False: attention without positions (Jamba)
     rope_theta: float = 500000.0
     logit_softcap: float = 0.0
 
@@ -130,6 +131,11 @@ class ArchConfig:
         """Mamba inner width."""
         return self.ssm_expand * self.d_model
 
+    @property
+    def dt_rank(self) -> int:
+        """Mamba's time-step rank, ``ceil(d_model / 16)`` (its "auto")."""
+        return -(-self.d_model // 16)
+
     # Parameter accounting -------------------------------------------------
     def param_count(self) -> int:
         """Exact parameter count of the model as constructed by models/model.py."""
@@ -161,14 +167,15 @@ class ArchConfig:
         mamba = 0
         if kind in (MAMBA, MAMBA_MOE):
             attn = 0  # mamba blocks replace attention entirely
-            di, n = self.d_inner, self.ssm_state_dim
+            di, n, r = self.d_inner, self.ssm_state_dim, self.dt_rank
             mamba = (
                 2 * d * di            # in_proj (x and z branches)
-                + di * self.ssm_conv_width
-                + di * (n * 2 + 1)    # B, C, dt projections (x -> B,C,dt)
+                + di * self.ssm_conv_width + di   # conv weight and bias
+                + di * (r + 2 * n)    # x_proj (x -> dt, B, C)
+                + r + 2 * n           # RMSNorm gains of dt, B, C
+                + r * di + di         # dt_proj and its bias
                 + di * n              # A_log
                 + di                  # D skip
-                + di                  # dt bias
                 + di * d              # out_proj
                 + d                   # pre-norm
             )

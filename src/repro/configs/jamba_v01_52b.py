@@ -2,8 +2,10 @@
 
 32L d_model=4096 32H (GQA kv=8) d_ff=14336 vocab=65536, MoE 16 experts top-2.
 Mamba + attention at a 1:7 ratio (one attention layer per 8), MoE on every
-other layer.  Sub-quadratic overall: Mamba layers decode from O(1) state; the
-four attention layers hold the (sharded) KV cache.  Runs long_500k.
+other layer.  The Mamba-1 mixer as published (models/ssm.py): d_state
+16, d_conv 4 with a conv bias, expand 2, dt_rank 256.  Sub-quadratic
+overall: Mamba layers decode from O(1) state; the four attention layers
+hold the (sharded) KV cache.  Runs long_500k.
 [arXiv:2403.19887; hf]
 """
 

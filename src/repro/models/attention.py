@@ -51,8 +51,9 @@ def init_attention(cfg, key, cross: bool = False):
     return params, axes
 
 
-def _project_qkv(cfg, p, x, positions, use_rope: bool = True):
-    """x (B,S,D) -> q (B,S,H,hd), k/v (B,S,KV,hd)."""
+def _project_qkv(cfg, p, x, positions):
+    """x (B,S,D) -> q (B,S,H,hd), k/v (B,S,KV,hd); rotary positions unless
+    the config turns them off (``cfg.use_rope``)."""
     B, S, _ = x.shape
     q = dense(x, p["wq"]).reshape(B, S, cfg.num_heads, cfg.head_dim)
     k = dense(x, p["wk"]).reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
@@ -60,7 +61,7 @@ def _project_qkv(cfg, p, x, positions, use_rope: bool = True):
     if cfg.qk_norm:
         q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
-    if use_rope:
+    if cfg.use_rope:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
     return q, k, v

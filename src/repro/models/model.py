@@ -193,10 +193,10 @@ class LM:
         ``t`` is reset to ``prompt_len`` so decode overwrites the pad slots
         in order.  Because prefill attention is causal and pads sit at the
         end, positions < prompt_len never attend a pad slot, and decode masks
-        slots > t — pad KV is dead until overwritten.  Only valid for padded
-        inputs on architectures whose per-position state is causal-local
-        (pure attention stacks); SSM/xLSTM recurrences would fold pad tokens
-        into their state, so callers pass exact-length inputs there.
+        slots > t — pad KV is dead until overwritten.  Mamba blocks take
+        ``prompt_len`` too and keep the pads out of their recurrent state
+        (models/ssm.py).  xLSTM recurrences and MoE capacity routing would
+        still let pads in, so callers pass exact-length inputs there.
 
         ``prompt_len`` may also be a (B,) vector — mixed-length prompts
         sharing one padded batch (the micro-batching lane back-fill): each
@@ -209,7 +209,7 @@ class LM:
         img = batch.get("image_embeds")
         x, caches, _ = tf.run_stack(cfg, params["blocks"], x, mode="prefill",
                                     caches=caches, image_embeds=img,
-                                    remat=False)
+                                    remat=False, prompt_len=prompt_len)
         if prompt_len is None:
             last = x[:, -1:, :]
         else:

@@ -65,8 +65,9 @@ def init_block(cfg, kind: str, key):
 
 
 def apply_block(cfg, kind: str, p, x, *, mode: str, cache=None,
-                image_embeds=None):
-    """Returns (x, new_cache, aux_loss)."""
+                image_embeds=None, prompt_len=None):
+    """Returns (x, new_cache, aux_loss).  ``prompt_len`` marks a bucketed
+    prefill's pads, which recurrent blocks keep out of their state."""
     aux = jnp.zeros((), jnp.float32)
     if kind in (ATTN, ATTN_MOE):
         x, new_cache = attn_lib.attn_block(cfg, p["attn"], x, mode=mode,
@@ -77,7 +78,7 @@ def apply_block(cfg, kind: str, p, x, *, mode: str, cache=None,
                                             cache=cache)
     elif kind in (MAMBA, MAMBA_MOE):
         x, new_cache = ssm_lib.mamba_block(cfg, p["mamba"], x, mode=mode,
-                                           cache=cache)
+                                           cache=cache, prompt_len=prompt_len)
     elif kind == SLSTM:
         return (*xlstm_lib.slstm_block(cfg, p, x, mode=mode, cache=cache), aux)
     elif kind == MLSTM:
@@ -93,11 +94,12 @@ def apply_block(cfg, kind: str, p, x, *, mode: str, cache=None,
 
 
 def run_stack(cfg, blocks_params, x, *, mode: str, caches=None,
-              image_embeds=None, remat: bool = True):
+              image_embeds=None, remat: bool = True, prompt_len=None):
     """Scan the pattern x repeats stack.
 
     blocks_params: tuple over pattern positions, leaves stacked (repeats, ...).
     caches: matching stacked cache pytree (or None).
+    prompt_len: a bucketed prefill's true length(s) (``apply_block``).
     Returns (x, new_caches, aux_total).
     """
     pattern = cfg.block_pattern
@@ -107,7 +109,7 @@ def run_stack(cfg, blocks_params, x, *, mode: str, caches=None,
     # instead of sum-over-blocks (8 blocks/step for jamba).
     def block_fn(kind, p, x, c):
         return apply_block(cfg, kind, p, x, mode=mode, cache=c,
-                           image_embeds=image_embeds)
+                           image_embeds=image_embeds, prompt_len=prompt_len)
 
     if mode == "train" and remat:
         block_fn = jax.checkpoint(block_fn, prevent_cse=False,

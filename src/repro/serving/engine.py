@@ -31,10 +31,11 @@ response to free the dispatch slot within ``segment_len`` tokens.
   triggers O(log max_len) jit compiles instead of one per distinct prompt
   length.  The true ``prompt_len`` rides into the jitted prefill as a
   dynamic scalar: logits are gathered at ``prompt_len - 1`` and the cache
-  fill level is reset to ``prompt_len`` (models/model.py).  Padded prefill
-  is only bit-safe for causal-local stacks, so bucketing engages when the
-  block pattern is pure attention and falls back to exact lengths (the seed
-  behavior) otherwise.
+  fill level is reset to ``prompt_len`` (models/model.py).  Bucketing
+  engages when every block keeps pads out of its state: attention (causal,
+  pads at the end) and Mamba (pads masked out of the recurrence,
+  models/ssm.py); other stacks prefill at exact lengths (the seed
+  behavior).
 * **Ring-buffer KV cache** — caches hold ``max_len`` slots; decode writes
   step ``t`` at slot ``t % max_len`` (models/attention.py), so capacity is
   an attention-window bound, never a per-request reallocation.
@@ -87,10 +88,30 @@ def _top2(row: np.ndarray) -> tuple:
     return float(first), float(second)
 
 
-# Padded (bucketed) prefill is only used when every block's per-position
-# state is causal-local; SSM/xLSTM recurrences fold pad tokens into their
-# state and MoE capacity routing lets pad tokens evict real ones.
-_BUCKET_SAFE_KINDS = ("attn",)
+# Padded (bucketed) prefill is only used when every block keeps pad tokens
+# out of its state: attention is causal with the pads at the end, and a
+# Mamba mixer masks them out of its recurrence.  xLSTM recurrences fold
+# pads into their state and MoE capacity routing lets pads evict real
+# tokens.
+_BUCKET_SAFE_KINDS = ("attn", "mamba")
+
+
+def _pure_attention(cfg) -> bool:
+    """Speculative verification and the paged pool work on attention
+    caches alone."""
+    return all(k == "attn" for k in cfg.block_pattern)
+
+
+def recurrent_state_bytes(cfg) -> int:
+    """Bytes of recurrent state (Mamba conv and SSM, xLSTM cells) one
+    request carries through decode, whatever its length."""
+    import jax
+    from repro.configs.base import SUBQUADRATIC_KINDS
+    from repro.models.model import LM
+    caches = jax.eval_shape(lambda: LM(cfg).init_cache(1, 1))
+    return sum(leaf.size * leaf.dtype.itemsize
+               for kind, c in zip(cfg.block_pattern, caches)
+               if kind in SUBQUADRATIC_KINDS for leaf in jax.tree.leaves(c))
 
 
 class RealEngine:
@@ -128,6 +149,10 @@ class RealEngine:
         # prefill/decode/decode_segment spans (from the caller's now_fn)
         self.recorder = None
         self._pending_items: list = []
+        # pad tokens run through bucketed prefills (masked out of every
+        # state), and the recurrent state a request carries
+        self.prefill_pad_tokens = 0
+        self.recurrent_state_bytes = recurrent_state_bytes(cfg)
 
         self._bucketing = all(k in _BUCKET_SAFE_KINDS
                               for k in cfg.block_pattern)
@@ -149,13 +174,12 @@ class RealEngine:
         self.draft_lm = None
         self.draft_params = None
         if self.speculative:
-            if not self._bucketing:
+            if not _pure_attention(cfg):
                 raise ValueError(
                     "speculative decoding needs a pure-attention stack "
                     f"(got pattern {cfg.block_pattern}): the verify "
                     "forward is an attention-cache operation")
-            if not all(k in _BUCKET_SAFE_KINDS
-                       for k in draft_cfg.block_pattern):
+            if not _pure_attention(draft_cfg):
                 raise ValueError(
                     "draft model needs a pure-attention stack "
                     f"(got pattern {draft_cfg.block_pattern})")
@@ -177,7 +201,9 @@ class RealEngine:
     def engine_stats(self) -> dict:
         """Wire-facing stats snapshot (sidecar /healthz, /metrics)."""
         return {"replica": self.replica_id, "served": self.served,
-                "speculative": self.speculative}
+                "speculative": self.speculative,
+                "prefill_pad_tokens": self.prefill_pad_tokens,
+                "recurrent_state_bytes": self.recurrent_state_bytes}
 
     def _decoder(self, segment_len: int):
         dec = self._decoders.get(segment_len)
@@ -188,6 +214,12 @@ class RealEngine:
         return dec
 
     # -------------------------------------------------------------- prefill
+    def bucket(self, plen: int) -> int:
+        """The length a ``plen``-token prompt prefills at: its bucket, or
+        exactly ``plen`` where the stack does not bucket."""
+        from repro.serving.generate import bucket_for
+        return bucket_for(plen, self.buckets) if self._bucketing else plen
+
     def _run_prefill(self, prompt_ids: np.ndarray, prefill=None,
                      params=None):
         """Bucket-pad + prefill.  Returns (last_logits, caches, prompt_len).
@@ -195,16 +227,13 @@ class RealEngine:
         model prefills through the same bucketing so its cache rows are
         laid out identically)."""
         import jax.numpy as jnp
-        from repro.serving.generate import bucket_for
         ids = np.asarray(prompt_ids, np.int32).reshape(-1)
         plen = len(ids)
         if plen < 1:
             raise ValueError("empty prompt: prefill needs >= 1 token "
                              "(dynamic_slice would silently clamp to 0)")
-        if self._bucketing:
-            bucket = bucket_for(plen, self.buckets)
-        else:
-            bucket = plen                     # exact length (seed behavior)
+        bucket = self.bucket(plen)
+        self.prefill_pad_tokens += bucket - plen
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :plen] = ids
         logits, caches = (prefill or self._prefill)(
@@ -231,18 +260,15 @@ class RealEngine:
         two."""
         import jax
         import jax.numpy as jnp
-        from repro.serving.generate import bucket_for
         ids_list = [np.asarray(i, np.int32).reshape(-1) for i in ids_list]
         plens = [len(i) for i in ids_list]
         if min(plens) < 1:
             raise ValueError("empty prompt in prefill group")
-        if self._bucketing:
-            buckets = {bucket_for(p, self.buckets) for p in plens}
-        else:
-            buckets = set(plens)           # exact lengths (seed behavior)
+        buckets = {self.bucket(p) for p in plens}
         if len(buckets) != 1:
             raise ValueError(f"prefill group spans buckets {buckets}")
         bucket = buckets.pop()
+        self.prefill_pad_tokens += sum(bucket - p for p in plens)
         k = len(ids_list)
         if pad_rows is not None:
             if k > pad_rows:
@@ -287,7 +313,8 @@ class RealEngine:
             rec.region, req_id=req_id, track=f"replica{self.replica_id}")
         t0 = time.monotonic()
         with NO_REGION if region is None else region(
-                "prefill", tokens=len(prompt_ids)):
+                "prefill", tokens=len(prompt_ids),
+                bucket=self.bucket(len(prompt_ids))):
             logits, caches, plen = self._run_prefill(prompt_ids)
             tok = int(np.argmax(np.asarray(logits)[0]))
         ttft = time.monotonic() - t0
@@ -570,13 +597,9 @@ class BatchedRealEngine(RealEngine):
         """Prefill admitted claims per bucket group (rows pad exactly as
         their solo prefill would, so per-lane results match the serial
         path bitwise) — one jit call + one lane insert per group."""
-        from repro.serving.generate import bucket_for
-
-        def bucket_of(n):
-            return bucket_for(n, self.buckets) if self._bucketing else n
         groups: dict = {}
         for claim in claims:
-            groups.setdefault(bucket_of(len(claim[2])), []).append(claim)
+            groups.setdefault(self.bucket(len(claim[2])), []).append(claim)
         for group in groups.values():
             logits, pcache, plens = self._run_prefill_group(
                 [ids for _, _, ids, _ in group], pad_rows=self.n_lanes)
@@ -863,7 +886,7 @@ class PagedBatchedEngine(BatchedRealEngine):
                          n_lanes=n_lanes, budget_bytes=budget_bytes,
                          draft_cfg=draft_cfg, draft_params=draft_params,
                          draft_k=draft_k, draft_seed=draft_seed)
-        if not self._bucketing:
+        if not _pure_attention(cfg):
             raise ValueError("block-paged KV needs a pure-attention stack "
                              f"(got pattern {cfg.block_pattern})")
         if max_len % page_size:

@@ -939,7 +939,8 @@ class Observability:
 
     def register_engines(self, engines) -> None:
         """Export lane occupancy / dead steps / accept rate / page states
-        from engine ``stats()`` dicts at scrape time."""
+        / prefill pads / recurrent state from engine ``stats()`` dicts at
+        scrape time."""
         if self.metrics is None:
             return
         reg = self.metrics
@@ -951,6 +952,11 @@ class Observability:
                           "Cumulative draft-token acceptance rate")
         g_pages = reg.gauge("clairvoyant_pages",
                             "KV pool pages by state (free/cached/held)")
+        c_pad = reg.counter("clairvoyant_prefill_pad_tokens_total",
+                            "Pad tokens run through bucketed prefills and "
+                            "masked out of their state")
+        g_rec = reg.gauge("clairvoyant_recurrent_state_bytes",
+                          "Recurrent state bytes a request carries")
 
         def collect():
             for eng in engines:
@@ -967,6 +973,9 @@ class Observability:
                     c_dead.set_total(st["dead_steps"], **lab)
                 if st.get("accept_rate") is not None:
                     g_acc.set(st["accept_rate"], **lab)
+                if "prefill_pad_tokens" in st:
+                    c_pad.set_total(st["prefill_pad_tokens"], **lab)
+                    g_rec.set(st["recurrent_state_bytes"], **lab)
                 pages = st.get("pages")
                 if isinstance(pages, dict):
                     for state in ("free", "cached", "held"):

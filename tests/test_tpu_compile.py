@@ -200,3 +200,34 @@ def test_paged_lane_segment_compiles(one_chip, lm):
         params, caches, lane, lane, lane, lane, _scalar(jnp.int32, one_chip),
         _scalar(jnp.bool_, one_chip, (LANES,))).compile()
     _fits(compiled)
+
+
+@pytest.fixture(scope="module")
+def jamba_lm():
+    return LM(get_config("jamba2-3b"))
+
+
+@pytest.mark.parametrize("bucket", [16, 64])
+def test_jamba_bucketed_prefill_compiles(one_chip, jamba_lm, bucket):
+    """jamba2-3b at its published widths (26 Mamba-1 mixers, 2 attention
+    layers, 6.06 GB of weights): the masked recurrent prefill at the
+    buckets its short prompts use compiles and fits one chip."""
+    params = _spec(jamba_lm.abstract_params()[0], one_chip)
+    prefill = jax.jit(lambda p, toks, plen: jamba_lm.prefill(
+        p, {"tokens": toks}, pad_to=MAX_LEN, prompt_len=plen))
+    compiled = prefill.lower(
+        params, _scalar(jnp.int32, one_chip, (1, bucket)),
+        _scalar(jnp.int32, one_chip)).compile()
+    assert _fits(compiled) < 6.3e9
+
+
+def test_jamba_fused_segment_compiles(one_chip, jamba_lm):
+    from repro.serving.generate import FusedDecoder
+    params = _spec(jamba_lm.abstract_params()[0], one_chip)
+    caches = _spec(jax.eval_shape(lambda: jamba_lm.init_cache(1, MAX_LEN)),
+                   one_chip)
+    i32 = _scalar(jnp.int32, one_chip)
+    dec = FusedDecoder(jamba_lm, MAX_LEN, SEGMENT_LEN)
+    compiled = dec._segment.lower(params, caches, i32, i32, i32, i32,
+                                  i32).compile()
+    assert _fits(compiled) < 6.3e9
